@@ -129,4 +129,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
     sys.exit(main())

@@ -106,7 +106,10 @@ class ModelConfig:
     attn_logit_softcap: Optional[float] = None
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
-    dtype: str = "bfloat16"
+    dtype: str = "bfloat16"  # activations
+    # weights at rest: fp32 master weights for training; serving keeps them
+    # in the activation dtype, as published checkpoints ship them
+    param_dtype: str = "float32"
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     recurrent: Optional[RecurrentConfig] = None

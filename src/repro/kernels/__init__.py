@@ -15,3 +15,24 @@ dry-run/roofline path intentionally lowers the pure-jnp implementations
 (``use_pallas=False``) so ``cost_analysis()`` sees real FLOPs — a Pallas
 custom-call is opaque to XLA's cost model.
 """
+
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The wrappers' ``interpret=None`` default: compiled on the TPU,
+    interpreted on the CPU. Any other backend raises instead of silently
+    running the interpreter off the device."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels do not run on the {backend!r} backend; "
+        "pass interpret=True to emulate them"
+    )

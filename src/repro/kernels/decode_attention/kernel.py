@@ -28,7 +28,7 @@ NEG_INF = -1.0e30
 
 
 def _decode_kernel(
-    len_ref,  # (1, 1) int32 — valid cache length for this batch row
+    len_ref,  # (B,) int32 — scalar prefetch: valid cache length per batch row
     q_ref,    # (1, 1, G, hd)
     k_ref,    # (1, 1, bk, hd)
     v_ref,    # (1, 1, bk, hd)
@@ -51,7 +51,7 @@ def _decode_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # the wrapper pre-clamps rolling caches: limit = min(kv_len, true_skv)
-    limit = len_ref[0, 0]
+    limit = len_ref[pl.program_id(0)]
     needed = ki * bk < limit
 
     @pl.when(needed)
@@ -87,8 +87,8 @@ def _paged_decode_kernel(
     pt_ref,   # (B, NP) int32 — scalar prefetch: physical page per logical page
     len_ref,  # (B,) int32    — scalar prefetch: valid cache length per slot
     q_ref,    # (1, 1, G, hd)
-    k_ref,    # (1, ps, 1, hd) — one physical page, one KV head
-    v_ref,    # (1, ps, 1, hd)
+    k_ref,    # (1, ps, hd) — one physical page, one KV head
+    v_ref,    # (1, ps, hd)
     o_ref,    # (1, 1, G, hd)
     m_scr, l_scr, acc_scr,  # (G, 1), (G, 1), (G, hd)
     *,
@@ -112,8 +112,8 @@ def _paged_decode_kernel(
     @pl.when(needed)
     def _page():
         q = q_ref[0, 0].astype(jnp.float32)        # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (ps, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)  # (ps, hd)
+        v = v_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (G, ps)
@@ -158,11 +158,19 @@ def paged_decode_attention_pallas(
     Grid steps past the slot's last occupied page re-request that same
     page (the wrapper clamps the table), so the pipeline's block-index
     change detection elides their copies; ``pl.when`` skips their compute.
+
+    The pool is viewed as (P, ps, Hkv·hd) — a free reshape — so one KV
+    head of one page is the (ps, hd) block at lane offset h·hd. A
+    (1, ps, 1, hd) block of the 4-D pool has a second-minor block size of
+    1 over Hkv, which the TPU lowering refuses; this view needs only
+    ps % 8 == 0 and hd % 128 == 0 (or Hkv == 1).
     """
     B, Hkv, G, hd = q.shape
-    _, ps, _, _ = k_pages.shape
+    P, ps, _, _ = k_pages.shape
     NP = page_table.shape[1]
     scale = hd**-0.5
+    k_pages = k_pages.reshape(P, ps, Hkv * hd)
+    v_pages = v_pages.reshape(P, ps, Hkv * hd)
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, softcap=softcap, ps=ps, np_max=NP
@@ -175,10 +183,10 @@ def paged_decode_attention_pallas(
                 (1, 1, G, hd), lambda b, h, ki, pt, lens: (b, h, 0, 0)
             ),
             pl.BlockSpec(
-                (1, ps, 1, hd), lambda b, h, ki, pt, lens: (pt[b, ki], 0, h, 0)
+                (1, ps, hd), lambda b, h, ki, pt, lens: (pt[b, ki], 0, h)
             ),
             pl.BlockSpec(
-                (1, ps, 1, hd), lambda b, h, ki, pt, lens: (pt[b, ki], 0, h, 0)
+                (1, ps, hd), lambda b, h, ki, pt, lens: (pt[b, ki], 0, h)
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -202,7 +210,7 @@ def decode_attention_pallas(
     q: jax.Array,       # (B, Hkv, G, hd)
     k_cache: jax.Array, # (B, Hkv, Skv, hd)
     v_cache: jax.Array,
-    kv_len: jax.Array,  # (B, 1) int32
+    kv_len: jax.Array,  # (B,) int32
     *,
     rolling: bool,
     softcap: Optional[float],
@@ -219,21 +227,26 @@ def decode_attention_pallas(
         scale=scale, softcap=softcap, rolling=rolling,
         skv=Skv_p, bk=bk, nk=nk,
     )
-    return pl.pallas_call(
-        kernel,
+    # kv_len rides in SMEM as a scalar-prefetch operand: a per-row (1, 1)
+    # SMEM block of a (B, 1) array is refused by the TPU lowering
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ki: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, ki, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, ki, lens: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, ki, lens: (b, h, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ki, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         interpret=interpret,
     )(kv_len, q, k_cache, v_cache)

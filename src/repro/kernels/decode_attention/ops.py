@@ -9,14 +9,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.decode_attention.kernel import (
     decode_attention_pallas,
     paged_decode_attention_pallas,
 )
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("rolling", "softcap", "bk", "interpret"))
@@ -31,8 +28,7 @@ def decode_attention(
     bk: int = 512,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = not _is_tpu()
+    interpret = resolve_interpret(interpret)
     B, H, hd = q.shape
     _, Skv, Hkv, _ = k_cache.shape
     G = H // Hkv
@@ -43,7 +39,7 @@ def decode_attention(
     # clamp to the physical cache: rolling caches wrap (every slot valid once
     # kv_len >= Skv) and linear caches can never hold more than Skv entries —
     # either way padded slots past Skv must stay masked.
-    kv_len = jnp.minimum(kv_len, Skv).reshape(B, 1)
+    kv_len = jnp.minimum(kv_len, Skv)
 
     bk = min(bk, max(128, 1 << (Skv - 1).bit_length()))
     pad = (-Skv) % bk
@@ -77,8 +73,7 @@ def paged_decode_attention(
     clamp + page-table tail clamp, then the Pallas kernel. A slot's cache
     capacity is ``NP * ps``; like the dense wrapper, kv_len is clamped to
     it (rolling caches wrap — every allocated slot valid once full)."""
-    if interpret is None:
-        interpret = not _is_tpu()
+    interpret = resolve_interpret(interpret)
     B, H, hd = q.shape
     P, ps, Hkv, _ = k_pages.shape
     NP = page_table.shape[1]

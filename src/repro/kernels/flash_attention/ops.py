@@ -10,11 +10,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -34,8 +31,7 @@ def flash_attention(
     bk: int = 128,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = not _is_tpu()
+    interpret = resolve_interpret(interpret)
     B, Sq, H, hd = q.shape
     _, Sk, Hkv, _ = k.shape
     G = H // Hkv

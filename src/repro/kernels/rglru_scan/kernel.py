@@ -35,12 +35,11 @@ def _rglru_kernel(a_ref, b_ref, o_ref, s_scr, *, bt: int):
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    a = a_ref[0]  # (bt, bw) fp32
-    b = b_ref[0]
-
+    # rows are read from the refs: indexing a loaded (bt, bw) value at a
+    # traced t is a dynamic_slice, which has no TPU lowering
     def step(t, s):
-        s = a[t, :][None, :] * s + b[t, :][None, :]  # (1, bw)
-        o_ref[0, t, :] = s[0, :].astype(o_ref.dtype)
+        s = a_ref[0, pl.ds(t, 1), :] * s + b_ref[0, pl.ds(t, 1), :]  # (1, bw)
+        o_ref[0, pl.ds(t, 1), :] = s.astype(o_ref.dtype)
         return s
 
     s = jax.lax.fori_loop(0, bt, step, s_scr[...])

@@ -7,11 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.rglru_scan.kernel import rglru_scan_pallas
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "bw", "interpret"))
@@ -23,8 +20,7 @@ def rglru_scan(
     bw: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = not _is_tpu()
+    interpret = resolve_interpret(interpret)
     B, S, W = a.shape
     bt = min(bt, max(8, 1 << (S - 1).bit_length()))
     bw = min(bw, max(128, 1 << (W - 1).bit_length()))

@@ -7,14 +7,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.tiered_gather.kernel import (
     tiered_gather_matmul_pallas,
     tiered_gather_pallas,
 )
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("group_size", "interpret"))
@@ -28,8 +25,7 @@ def tiered_gather(
 ) -> tuple[jax.Array, jax.Array]:
     """Gather rows with residency check. Returns (rows (N, D) — zeros for
     misses, miss (N,) int32)."""
-    if interpret is None:
-        interpret = not _is_tpu()
+    interpret = resolve_interpret(interpret)
     ids = ids.astype(jnp.int32)
     group_mask = group_mask.astype(jnp.int32)
     return tiered_gather_pallas(
@@ -51,8 +47,7 @@ def tiered_gather_matmul(
     (out (N, F) — table[ids] @ w with zeros for misses, miss (N,) int32);
     cold rows are skipped (no DMA, no MXU work), not zero-filled-and-
     multiplied."""
-    if interpret is None:
-        interpret = not _is_tpu()
+    interpret = resolve_interpret(interpret)
     ids = ids.astype(jnp.int32)
     group_mask = group_mask.astype(jnp.int32)
     return tiered_gather_matmul_pallas(

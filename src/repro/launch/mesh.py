@@ -17,12 +17,13 @@ logical-axis rules (repro.sharding) compose "batch" over ("pod", "data").
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
@@ -43,7 +44,11 @@ def make_debug_mesh(data: int = 1, model: int = 1):
             f"{have} exist; set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{data * model} before the first jax init (see launch/dryrun.py)"
         )
-    return jax.make_mesh((data, model), ("data", "model"))
+    # Auto axes: shardings propagate through jit and .at[].set as they did
+    # before make_mesh defaulted to Explicit axes, under which installing a
+    # unit into a sharded leaf cannot resolve its out sharding
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_label(mesh) -> str:

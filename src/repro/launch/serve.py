@@ -46,11 +46,11 @@ import threading
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.manager import clean_partials
 from repro.configs import get_config, get_reduced
+from repro.configs.base import ModelConfig
 from repro.core import (
     AccessTrace,
     DeploymentProfile,
@@ -65,21 +65,49 @@ from repro.core import (
 )
 from repro.core import snapshot as server_snapshot
 from repro.data import DataConfig, SyntheticTokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models.zoo import build_model
-from repro.optim import init_adamw
 from repro.serving import (
+    ColdStartServer,
     ContinuousBatchingScheduler,
     GenerationEngine,
     SLOAdmission,
     cold_start,
 )
+from repro.sharding.rules import param_shardings
 
 
-def main(argv=None) -> int:
+def cut_depth(cfg: ModelConfig, layers: int) -> ModelConfig:
+    """The published config with only its first ``layers`` layers; every
+    width stays as published. The cut must keep every kind of layer the
+    model has (a whole period of its layer pattern) and, for MoE models
+    with leading dense layers, at least one expert layer."""
+    if not 1 <= layers <= cfg.num_layers:
+        raise ValueError(f"--layers wants 1..{cfg.num_layers} for {cfg.name}, got {layers}")
+    cut = cfg.replace(name=f"{cfg.name}-{layers}L", num_layers=layers)
+    missing = set(cfg.attn_kinds) - set(cut.attn_kinds)
+    if missing:
+        raise ValueError(f"--layers {layers} drops {sorted(missing)} layers of {cfg.name}; "
+                         f"keep a whole period of {sorted(set(cfg.attn_kinds))}")
+    if cfg.moe is not None and layers <= cfg.moe.first_dense_layers:
+        raise ValueError(f"--layers {layers} keeps only the leading dense layers of "
+                         f"{cfg.name} ({cfg.moe.first_dense_layers}); keep an expert layer")
+    return cut
+
+
+def parse_args(argv=None):
+    """The launcher's command line -> ``(args, mesh)``; usage errors exit 2."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="the architecture's toy-width CPU variant")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only the first N layers, every width as "
+                         "published: the depth cut that fits one chip "
+                         "(0 = published depth)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and of the prompts")
     ap.add_argument("--mode", default="after2", choices=["before", "after1", "after2"])
     ap.add_argument("--artifact-dir", default="artifacts")
     ap.add_argument("--batch", type=int, default=2)
@@ -156,6 +184,13 @@ def main(argv=None) -> int:
                          "and pushes the learned hot set to all of them "
                          "(implies --retier-online; after2 one-shot only)")
     args = ap.parse_args(argv)
+    if args.layers:
+        if args.reduced:
+            ap.error("--layers cuts the published config; --reduced is a toy-width one")
+        try:
+            cut_depth(get_config(args.arch), args.layers)
+        except ValueError as e:
+            ap.error(str(e))
     if (args.profile_out or args.retier_from or args.retier_online) and args.mode != "after2":
         ap.error("--profile-out/--retier-from/--retier-online need the "
                  "two-tier runtime (--mode after2)")
@@ -206,12 +241,29 @@ def main(argv=None) -> int:
         ap.error("--retier-from drives the predictive prefetcher; drop "
                  "--no-prefetch / use --policy stats|full (profiling runs "
                  "want --no-prefetch, re-serve runs don't)")
+    return args, mesh
 
-    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
-    cfg = cfg.replace(collect_moe_usage=cfg.moe is not None)
-    model = build_model(cfg)
-    outdir = os.path.join(args.artifact_dir, cfg.name)
 
+def load_config(args) -> ModelConfig:
+    """The architecture's config: toy widths (``--reduced``), the published
+    config cut in depth (``--layers``), or the published config."""
+    if args.reduced:
+        cfg = get_reduced(args.arch)
+    else:
+        full = get_config(args.arch)
+        cfg = cut_depth(full, args.layers) if args.layers else full
+        print(f"[serve] {cfg.name}: {cfg.num_layers} of {full.num_layers} layers at "
+              f"published widths (d_model {cfg.d_model}, heads {cfg.num_heads}/"
+              f"{cfg.num_kv_heads}, head_dim {cfg.resolved_head_dim}, d_ff {cfg.d_ff}"
+              + (f", {cfg.moe.num_experts} experts top-{cfg.moe.top_k} "
+                 f"d_ff {cfg.moe.expert_d_ff}" if cfg.moe else "")
+              + f", vocab {cfg.vocab_size}, window {cfg.sliding_window})")
+    return cfg.replace(collect_moe_usage=cfg.moe is not None, param_dtype=cfg.dtype)
+
+
+def analyze_plan(model, args):
+    """The deployment profile of ``--policy`` and the analyzer's plan."""
+    cfg = model.cfg
     if args.policy == "strict":
         profile = DeploymentProfile(resident_experts=0, hot_vocab_fraction=0.0,
                                     min_tier1_bytes=1 << 14, vocab_row_group=max(64, cfg.vocab_size // 16))
@@ -232,9 +284,25 @@ def main(argv=None) -> int:
     print(f"[serve] analyzing {cfg.name} under profile {profile.name}/{args.policy}")
     result = analyze(model, profile, hot_units_stats=stats, trace_B=1, trace_S=32)
     print("[serve] plan:", json.dumps(result.summary(), default=str)[:400])
+    return result
 
-    params = model.init(jax.random.PRNGKey(0))
-    opt = init_adamw(params)
+
+def init_weights(model, seed: int, mesh=None):
+    """Random weights from ``seed``, made on the device by one jitted
+    program (no fp32 temporaries of whole leaves) and, under a mesh,
+    sharded as ``cold_start`` shards them."""
+    shardings = None
+    if mesh is not None:
+        shardings = param_shardings(model.logical_axes(), model.abstract(), mesh,
+                                    fsdp=model.cfg.fsdp)
+    return jax.jit(model.init, out_shardings=shardings)(jax.random.PRNGKey(seed))
+
+
+def write_artifact(model, params, result, outdir: str, mode: str, *,
+                   compress_level: int = 6) -> None:
+    """Write what ``mode`` cold-starts from: the monolithic bundle
+    (before/after1, with AdamW state as host zeros — serving never reads
+    it, but the paper's baseline ships it) or the two-tier artifact."""
     os.makedirs(outdir, exist_ok=True)
     # crash recovery before any writer exists: a prior run killed mid-way
     # through an artifact rewrite (retier compaction, checkpoint save)
@@ -243,11 +311,26 @@ def main(argv=None) -> int:
     if removed:
         print(f"[serve] removed {len(removed)} orphaned partial(s): "
               + ", ".join(os.path.basename(p) for p in removed))
-    if args.mode in ("before", "after1"):
-        write_monolithic({"params": params, "opt_state": {"m": opt.m, "v": opt.v}},
-                         outdir, pruned=args.mode == "after1")
+    if mode in ("before", "after1"):
+        def host_zeros(a):
+            return np.zeros(a.shape, np.float32)
+
+        abstract = model.abstract()
+        opt = {"m": jax.tree.map(host_zeros, abstract), "v": jax.tree.map(host_zeros, abstract)}
+        write_monolithic({"params": params, "opt_state": opt}, outdir, pruned=mode == "after1")
     else:
-        build_artifact(params, result, outdir)
+        build_artifact(params, result, outdir, compress_level=compress_level)
+
+
+def main(argv=None) -> int:
+    args, mesh = parse_args(argv)
+    cfg = load_config(args)
+    model = build_model(cfg)
+    outdir = os.path.join(args.artifact_dir, cfg.name)
+    result = analyze_plan(model, args)
+    # the weights live only until the artifact holds them: the cold start
+    # begins from an empty device, and its loader is the only uploader
+    write_artifact(model, init_weights(model, args.seed, mesh), result, outdir, args.mode)
 
     predictor = None
     if args.retier_from:
@@ -266,68 +349,17 @@ def main(argv=None) -> int:
     if args.fleet:
         return _serve_fleet(model, result, outdir, args, cfg)
 
-    warm_B = 1 if args.concurrency > 0 else args.batch
     # the context manager guarantees prefetcher/store teardown even when
     # the request path raises (a leaked reader/uploader thread would hang
     # the process on exit)
     failed = 0
-    arbiter = HostArbiter(args.host_budget_bytes) if args.host_budget_bytes else None
-    admission = None
-    if args.admission == "slo":
-        admission = SLOAdmission(
-            default_deadline_s=(args.deadline_ms / 1e3) if args.deadline_ms else None
-        )
-    with cold_start(model, outdir, result if args.mode == "after2" else None,
-                    mode=args.mode, warm_shapes=((warm_B, args.prompt_len),),
-                    residency=args.policy if args.mode == "after2" else None,
-                    device_budget_bytes=args.device_budget_bytes or None,
-                    host_arbiter=arbiter,
-                    prefetch=False if args.no_prefetch else None,
-                    trace=bool(args.profile_out), predictor=predictor,
-                    retier_online=args.retier_online,
-                    retier_interval=args.retier_interval,
-                    retier_decay=args.retier_decay,
-                    retier_compact_every=args.retier_compact_every,
-                    mesh=mesh, admission=admission,
-                    restore_from=args.restore_from or None) as server:
-        print(f"[serve] cold start ({args.mode}):", json.dumps(server.report.to_dict(), default=float))
-        if server.restore_report is not None:
-            rr = server.restore_report
-            print(f"[serve] warm restore: {rr['restored']}/{rr['requested']} units "
-                  f"resident ({rr['moved_bytes']:,}B replayed, "
-                  f"predictor {'armed' if rr['predictor_armed'] else 'absent'})")
-
-        engine = GenerationEngine(server, max_seq=args.prompt_len + args.gen_steps + 8)
+    with open_server(model, result, outdir, args, mesh, predictor=predictor) as server:
+        engine = make_engine(server, args)
         if args.concurrency > 0:
-            failed = _serve_traffic(engine, args, cfg)
+            failed = serve_traffic(engine, args, cfg)
         else:
-            prompts = jax.random.randint(jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0, cfg.vocab_size)
-            out, stats_r = engine.generate(prompts, args.gen_steps)
-            print(f"[serve] generated {out.shape}; prefill={stats_r.prefill_s*1e3:.1f}ms "
-                  f"decode={stats_r.decode_s*1e3:.1f}ms faults={stats_r.faulted_units} "
-                  f"({stats_r.faulted_bytes/2**20:.1f}MiB, {stats_r.fault_s*1e3:.1f}ms)")
-        if server.tiered is not None:
-            ts = server.tiered.stats
-            budget = server.tiered.residency.budget_bytes
-            print(f"[serve] resident fraction: {server.tiered.resident_fraction():.3f}; "
-                  f"resident {server.tiered.resident_bytes:,}B"
-                  + (f" / budget {budget:,}B" if budget else " (no budget)"))
-            print(f"[serve] prefetch hit rate {ts.prefetch_hit_rate:.2f}; "
-                  f"evictions {ts.evictions}; refaults {ts.refaults}; "
-                  f"stall p99 {ts.stall_percentile(99)*1e3:.2f}ms")
-            if server.prefetcher is not None and server.prefetcher.predictor is not None:
-                ps = server.prefetcher.stats
-                print(f"[serve] predictor: observed {ps.observed} keys, "
-                      f"predicted {ps.predicted} ahead-of-schedule loads")
-        if arbiter is not None:
-            audit = arbiter.audit()
-            hs = arbiter.stats
-            print(f"[serve] host arbiter: {audit['resident_bytes']:,}B resident "
-                  f"/ {audit['budget_bytes']:,}B host budget "
-                  f"({audit['pinned_bytes']:,}B pinned); "
-                  f"{hs.evictions} evictions ({hs.evicted_bytes:,}B), "
-                  f"{hs.overshoots} overshoots, "
-                  f"{hs.headroom_denials} prefetch headroom denials")
+            generate_once(engine, args, cfg)
+        print_residency(server)
         if server.retier_daemon is not None:
             _print_daemon_stats(server)
         if args.profile_out and server.tiered is not None and server.tiered.trace is not None:
@@ -348,6 +380,84 @@ def main(argv=None) -> int:
     if failed:
         print(f"[serve] FAILED: {failed} request(s) failed or never finished")
     return 1 if failed else 0
+
+
+def open_server(model, result, outdir: str, args, mesh=None, *,
+                predictor=None) -> ColdStartServer:
+    """The timed cold start ``args`` describe (``--mode``, ``--policy``,
+    budgets, prefetch, re-tiering, mesh, admission, restore); prints its
+    report. Use it as a context manager."""
+    warm_B = 1 if args.concurrency > 0 else args.batch
+    arbiter = HostArbiter(args.host_budget_bytes) if args.host_budget_bytes else None
+    admission = None
+    if args.admission == "slo":
+        admission = SLOAdmission(
+            default_deadline_s=(args.deadline_ms / 1e3) if args.deadline_ms else None
+        )
+    server = cold_start(model, outdir, result if args.mode == "after2" else None,
+                        mode=args.mode, warm_shapes=((warm_B, args.prompt_len),),
+                        residency=args.policy if args.mode == "after2" else None,
+                        device_budget_bytes=args.device_budget_bytes or None,
+                        host_arbiter=arbiter,
+                        prefetch=False if args.no_prefetch else None,
+                        trace=bool(args.profile_out), predictor=predictor,
+                        retier_online=args.retier_online,
+                        retier_interval=args.retier_interval,
+                        retier_decay=args.retier_decay,
+                        retier_compact_every=args.retier_compact_every,
+                        mesh=mesh, admission=admission,
+                        restore_from=args.restore_from or None)
+    print(f"[serve] cold start ({args.mode}):", json.dumps(server.report.to_dict(), default=float))
+    if server.restore_report is not None:
+        rr = server.restore_report
+        print(f"[serve] warm restore: {rr['restored']}/{rr['requested']} units "
+              f"resident ({rr['moved_bytes']:,}B replayed, "
+              f"predictor {'armed' if rr['predictor_armed'] else 'absent'})")
+    return server
+
+
+def print_residency(server: ColdStartServer) -> None:
+    """The tier-1 residency layer's summary lines (after2 only)."""
+    if server.tiered is None:
+        return
+    ts = server.tiered.stats
+    budget = server.tiered.residency.budget_bytes
+    print(f"[serve] resident fraction: {server.tiered.resident_fraction():.3f}; "
+          f"resident {server.tiered.resident_bytes:,}B"
+          + (f" / budget {budget:,}B" if budget else " (no budget)"))
+    print(f"[serve] prefetch hit rate {ts.prefetch_hit_rate:.2f}; "
+          f"evictions {ts.evictions}; refaults {ts.refaults}; "
+          f"stall p99 {ts.stall_percentile(99)*1e3:.2f}ms")
+    if server.prefetcher is not None and server.prefetcher.predictor is not None:
+        ps = server.prefetcher.stats
+        print(f"[serve] predictor: observed {ps.observed} keys, "
+              f"predicted {ps.predicted} ahead-of-schedule loads")
+    arbiter = server.tiered.arbiter
+    if arbiter is not None:
+        audit = arbiter.audit()
+        hs = arbiter.stats
+        print(f"[serve] host arbiter: {audit['resident_bytes']:,}B resident "
+              f"/ {audit['budget_bytes']:,}B host budget "
+              f"({audit['pinned_bytes']:,}B pinned); "
+              f"{hs.evictions} evictions ({hs.evicted_bytes:,}B), "
+              f"{hs.overshoots} overshoots, "
+              f"{hs.headroom_denials} prefetch headroom denials")
+
+
+def make_engine(server: ColdStartServer, args) -> GenerationEngine:
+    return GenerationEngine(server, max_seq=args.prompt_len + args.gen_steps + 8)
+
+
+def generate_once(engine: GenerationEngine, args, cfg):
+    """The one-shot workload: one batched greedy ``generate`` of
+    ``--batch`` prompts. Returns ``(tokens (B, gen_steps), RequestStats)``."""
+    prompts = jax.random.randint(jax.random.PRNGKey(args.seed + 1),
+                                 (args.batch, args.prompt_len), 0, cfg.vocab_size)
+    out, st = engine.generate(prompts, args.gen_steps)
+    print(f"[serve] generated {out.shape}; prefill={st.prefill_s*1e3:.1f}ms "
+          f"decode={st.decode_s*1e3:.1f}ms faults={st.faulted_units} "
+          f"({st.faulted_bytes/2**20:.1f}MiB, {st.fault_s*1e3:.1f}ms)")
+    return out, st
 
 
 def _print_daemon_stats(server, label: str = "online retier") -> None:
@@ -393,9 +503,9 @@ def _serve_fleet(model, result, outdir, args, cfg) -> int:
             print(f"[serve] replica-{i} cold start:",
                   json.dumps(s.report.to_dict(), default=float))
         for i, s in enumerate(servers):
-            engine = GenerationEngine(s, max_seq=args.prompt_len + args.gen_steps + 8)
+            engine = make_engine(s, args)
             prompts = jax.random.randint(
-                jax.random.PRNGKey(1), (args.batch, args.prompt_len), 0, cfg.vocab_size)
+                jax.random.PRNGKey(args.seed + 1), (args.batch, args.prompt_len), 0, cfg.vocab_size)
             out, st = engine.generate(prompts, args.gen_steps)
             if out.shape[0] != args.batch:
                 failed += 1
@@ -421,15 +531,16 @@ def _serve_fleet(model, result, outdir, args, cfg) -> int:
     return 1 if failed else 0
 
 
-def _serve_traffic(engine: GenerationEngine, args, cfg) -> int:
+def serve_traffic(engine: GenerationEngine, args, cfg) -> int:
     """Open-loop traffic through the continuous-batching scheduler.
     Returns the number of failed/unfinished requests so the launcher can
     exit nonzero (CI smoke must catch silent request failures)."""
     sched = ContinuousBatchingScheduler(engine, max_batch=args.concurrency)
     sched.warm_compile()  # first step should serve, not compile
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     prompts = [
-        np.asarray(jax.random.randint(jax.random.PRNGKey(100 + i), (args.prompt_len,), 0, cfg.vocab_size))
+        np.asarray(jax.random.randint(jax.random.PRNGKey(args.seed + 100 + i),
+                                      (args.prompt_len,), 0, cfg.vocab_size))
         for i in range(args.requests)
     ]
     deadline_s = (args.deadline_ms / 1e3) if args.deadline_ms else None
@@ -479,4 +590,5 @@ def _serve_traffic(engine: GenerationEngine, args, cfg) -> int:
 
 
 if __name__ == "__main__":
+    print(f"[serve] compile cache: {enable_compile_cache()}")
     raise SystemExit(main())

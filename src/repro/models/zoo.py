@@ -8,6 +8,7 @@ exactly what the Program Analyzer traces and what the dry-run lowers.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
@@ -20,6 +21,7 @@ from repro.models import transformer as tf
 from repro.models import recurrent as rec_mod
 from repro.models import xlstm as xlstm_mod
 from repro.models.spec import (
+    ParamSpec,
     abstract_params,
     access_annotations,
     init_params,
@@ -52,7 +54,7 @@ class Model:
     def __init__(self, cfg: ModelConfig):
         cfg.validate()
         self.cfg = cfg
-        self.spec = tf.stack_spec(cfg)
+        self.spec = _with_param_dtype(tf.stack_spec(cfg), jnp.dtype(cfg.param_dtype))
         self.layout = tf.stack_layout(cfg)
 
     # -- params ------------------------------------------------------------
@@ -292,6 +294,16 @@ class Model:
         caxes = self.cache_axes(B, S, multimodal=multimodal)
         db, da = self.decode_batch_spec(B)
         return EntryPoint("decode_step", self.decode_step, (cache, db), (caxes, da), "decode")
+
+
+def _with_param_dtype(spec: Any, dtype) -> Any:
+    """Every floating ParamSpec at rest in ``dtype``."""
+    def cast(s: ParamSpec) -> ParamSpec:
+        if jnp.issubdtype(s.dtype, jnp.floating) and s.dtype != dtype:
+            return dataclasses.replace(s, dtype=dtype)
+        return s
+
+    return jax.tree.map(cast, spec, is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
 def build_model(cfg: ModelConfig) -> Model:

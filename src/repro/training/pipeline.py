@@ -21,7 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def gpipe_forward(
@@ -64,12 +63,12 @@ def gpipe_forward(
         return jax.lax.psum(out * mask, axis)
 
     pspecs = jax.tree.map(lambda _: P(axis), stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=(pspecs, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stacked_params, x)
 

@@ -212,6 +212,19 @@ GM_CASES = [
 ]
 
 
+def _assert_matmul_close(out, ref, table, w):
+    """Float32 gather-matmul outputs agree to the rounding of a length-D
+    dot product. The kernel and the reference both accumulate D fp32
+    products, but XLA:CPU may block or order the sums differently (the
+    installed version differs by ~1e-6), so bit-identity is not promised.
+    The standard bound on a length-D fp32 dot is D·eps·Σ|x_i·w_i|; its
+    largest value over the table's rows is the absolute tolerance."""
+    D = np.asarray(w).shape[0]
+    dot_abs = np.abs(np.asarray(table, np.float64)) @ np.abs(np.asarray(w, np.float64))
+    atol = D * np.finfo(np.float32).eps * dot_abs.max()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=0, atol=atol)
+
+
 def _gm_inputs(V, D, F, N, seed=0):
     kt, kw, ki = jax.random.split(jax.random.PRNGKey(seed or 42), 3)
     table = jax.random.normal(kt, (V, D), jnp.float32)
@@ -222,9 +235,8 @@ def _gm_inputs(V, D, F, N, seed=0):
 
 @pytest.mark.parametrize("case", GM_CASES)
 def test_gather_matmul_all_resident_matches_dense(case):
-    """All groups resident → bit-identical to the dense reference (gather
-    then einsum), miss mask all-zero: the fused kernel's fp32-accumulated
-    per-row dot is the same arithmetic as the reference matmul."""
+    """All groups resident → the dense reference (gather then einsum) to
+    fp32 dot rounding, miss mask all-zero."""
     V, D, F, N, gs = case
     table, w, ids = _gm_inputs(V, D, F, N)
     ids = jnp.clip(ids, 0, V - 1)  # keep every row a hit
@@ -234,7 +246,7 @@ def test_gather_matmul_all_resident_matches_dense(case):
     rout, rmiss = tiered_gather_matmul_ref(table, w, ids, mask, group_size=gs)
     np.testing.assert_array_equal(np.asarray(miss), 0)
     np.testing.assert_array_equal(np.asarray(miss), np.asarray(rmiss))
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(rout))
+    _assert_matmul_close(out, rout, table, w)
 
 
 @pytest.mark.parametrize("case", GM_CASES)
@@ -254,7 +266,7 @@ def test_gather_matmul_all_cold(case):
 @pytest.mark.parametrize("case", GM_CASES)
 def test_gather_matmul_mixed_residency(case):
     """Random residency + out-of-range ids: output rows match the masked
-    reference exactly, every miss row is exactly zero."""
+    reference to fp32 dot rounding, every miss row is exactly zero."""
     V, D, F, N, gs = case
     table, w, ids = _gm_inputs(V, D, F, N)
     G = (V + gs - 1) // gs
@@ -262,7 +274,7 @@ def test_gather_matmul_mixed_residency(case):
     out, miss = tiered_gather_matmul(table, w, ids, mask, group_size=gs, interpret=True)
     rout, rmiss = tiered_gather_matmul_ref(table, w, ids, mask, group_size=gs)
     np.testing.assert_array_equal(np.asarray(miss), np.asarray(rmiss))
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(rout))
+    _assert_matmul_close(out, rout, table, w)
     assert np.all(np.asarray(out)[np.asarray(miss) == 1] == 0)
 
 
@@ -306,7 +318,7 @@ def test_gather_matmul_property(v, n, gs, d, f):
     out, miss = tiered_gather_matmul(table, w, ids, mask, group_size=gs, interpret=True)
     rout, rmiss = tiered_gather_matmul_ref(table, w, ids, mask, group_size=gs)
     np.testing.assert_array_equal(np.asarray(miss), np.asarray(rmiss))
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(rout))
+    _assert_matmul_close(out, rout, table, w)
     assert np.all(np.asarray(out)[np.asarray(miss) == 1] == 0)
 
 
@@ -429,3 +441,29 @@ def test_paged_decode_property(ps, np_, hkv, g, hd, lens, rolling):
     vd = densify_pages(v_pages, pt)
     ref = decode_attention_ref(q, kd, vd, kv_len, rolling=rolling)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# interpret-mode default
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend,asked,want", [
+    ("tpu", None, False), ("cpu", None, True), ("gpu", True, True), ("tpu", True, True),
+])
+def test_resolve_interpret(monkeypatch, backend, asked, want):
+    """Compiled on the TPU, interpreted on the CPU or when asked."""
+    from repro import kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    assert kernels.resolve_interpret(asked) is want
+
+
+def test_resolve_interpret_refuses_other_backends(monkeypatch):
+    """A backend with no Pallas TPU lowering never falls back to the
+    interpreter unasked."""
+    from repro import kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        kernels.resolve_interpret(None)
