@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core.optional_store import OptionalStore, ReadStats
 from repro.core.partition import TierPlan, Unit
+from repro.utils.spans import span
 from repro.utils.tree import flatten_with_paths, tree_from_flat
 
 # residency states (DESIGN.md §8.1)
@@ -441,10 +442,6 @@ class LoaderStats:
         return sum(e.nbytes for e in self.events)
 
     @property
-    def total_miss_s(self) -> float:
-        return sum(e.fetch_s + e.upload_s for e in self.events if e.source != "prefetch")
-
-    @property
     def prefetch_hit_rate(self) -> float:
         """Of demand-touched cold units, fraction hidden by the prefetcher."""
         n = self.prefetch_hits + self.prefetch_waits + self.misses
@@ -815,10 +812,10 @@ class TieredParams:
             for base in range(0, len(ordered), CHUNK):
                 chunk = ordered[base:base + CHUNK]
                 try:
-                    tr0 = time.perf_counter()
                     rs = ReadStats()
-                    bufs = self.store.read_raw_many(chunk, stats=rs)
-                    t_read = time.perf_counter() - tr0
+                    with span("repro.tier1.read", source=source) as read:
+                        bufs = self.store.read_raw_many(chunk, stats=rs)
+                        read.nbytes = sum(len(b) for b in bufs.values())
                 except Exception:
                     with self._lock:
                         # roll back every not-yet-loaded claim, or they'd
@@ -833,9 +830,9 @@ class TieredParams:
                     self.store.entries[k].csize for k in chunk) or 1
                 for j, key in enumerate(chunk):
                     try:
-                        t0 = time.perf_counter()
-                        arr = self.store.decode(key, bufs[key])  # no lock
-                        t1 = time.perf_counter()
+                        with span("repro.tier1.decode", source=source) as dec:
+                            arr = self.store.decode(key, bufs[key])  # no lock
+                            dec.nbytes = arr.nbytes
                     except Exception:
                         with self._lock:
                             for k in ordered[base + j:]:
@@ -843,27 +840,28 @@ class TieredParams:
                         raise
                     # amortize the chunk's read wall csize-proportionally so
                     # per-event fetch_s still sums to time actually spent
-                    fetch_s = (t1 - t0) + t_read * (
+                    fetch_s = dec.seconds + read.seconds * (
                         self.store.entries[key].csize / total_csize)
-                    charge = self.unit_charge(key, arr.nbytes)
-                    if self.arbiter is not None:
-                        # cross-tenant make-room BEFORE taking our own lock
-                        # (arbiter lock orders first; it may lock other tenants)
-                        self.arbiter.make_room(self, charge)
-                    with self._lock:
-                        self._evict_to_fit(charge)
-                        self._install(self._all_units[key], arr)
-                        t2 = time.perf_counter()
-                        res.commit_load(key, charge, source)
-                        if res.was_evicted(key):
-                            self.stats.refaults += 1
-                        if source == "fault":  # preload is not a request-path miss
-                            self.stats.misses += 1
-                        self.stats.events.append(
-                            LoadEvent(key, arr.nbytes, fetch_s, t2 - t1,
-                                      t=time.monotonic(), source=source,
-                                      phase=self._phase)
-                        )
+                    with span("repro.tier1.install", source=source,
+                              nbytes=arr.nbytes) as inst:
+                        charge = self.unit_charge(key, arr.nbytes)
+                        if self.arbiter is not None:
+                            # cross-tenant make-room BEFORE taking our own lock
+                            # (arbiter lock orders first; it may lock other tenants)
+                            self.arbiter.make_room(self, charge)
+                        with self._lock:
+                            self._evict_to_fit(charge)
+                            self._install(self._all_units[key], arr)
+                            res.commit_load(key, charge, source)
+                            if res.was_evicted(key):
+                                self.stats.refaults += 1
+                            if source == "fault":  # preload is not a request-path miss
+                                self.stats.misses += 1
+                    self.stats.events.append(
+                        LoadEvent(key, arr.nbytes, fetch_s, inst.seconds,
+                                  t=time.monotonic(), source=source,
+                                  phase=self._phase)
+                    )
                     moved += arr.nbytes
 
         if wait_for:
@@ -878,7 +876,9 @@ class TieredParams:
                             finally:
                                 self._lock.acquire()
                             break
-                        if not res.wait_resident(k) and res.state_of(k) == LOADING:
+                        with span("repro.tier1.wait", source=source):
+                            became = res.wait_resident(k)
+                        if not became and res.state_of(k) == LOADING:
                             # never return with the key silently cold — the
                             # caller would compute on placeholder zeros
                             raise RuntimeError(
@@ -898,31 +898,33 @@ class TieredParams:
         """Synchronous load of one already-claimed key (takeover path)."""
         res = self.residency
         try:
-            t0 = time.perf_counter()
             rs = ReadStats()
-            arr = self.store.decode(key, self.store.read_raw(key, stats=rs))
-            t1 = time.perf_counter()
+            with span("repro.tier1.read", source=source) as read:
+                buf = self.store.read_raw(key, stats=rs)
+                read.nbytes = len(buf)
+            with span("repro.tier1.decode", source=source) as dec:
+                arr = self.store.decode(key, buf)
+                dec.nbytes = arr.nbytes
         except Exception:
             with self._lock:
                 res.abort_load(key)
             raise
         self.stats.preads_issued += rs.preads
         self.stats.frames_fetched += rs.frames
-        charge = self.unit_charge(key, arr.nbytes)
-        if self.arbiter is not None:
-            self.arbiter.make_room(self, charge)
-        with self._lock:
-            self._evict_to_fit(charge)
-            self._install(self._all_units[key], arr)
-            t2 = time.perf_counter()
-            res.commit_load(key, charge, source)
-            if source == "fault":
-                self.stats.misses += 1
-            self.stats.events.append(
-                LoadEvent(key, arr.nbytes, t1 - t0, t2 - t1,
-                          t=time.monotonic(), source=source,
-                          phase=self._phase)
-            )
+        with span("repro.tier1.install", source=source, nbytes=arr.nbytes) as inst:
+            charge = self.unit_charge(key, arr.nbytes)
+            if self.arbiter is not None:
+                self.arbiter.make_room(self, charge)
+            with self._lock:
+                self._evict_to_fit(charge)
+                self._install(self._all_units[key], arr)
+                res.commit_load(key, charge, source)
+                if source == "fault":
+                    self.stats.misses += 1
+        self.stats.events.append(
+            LoadEvent(key, arr.nbytes, read.seconds + dec.seconds, inst.seconds,
+                      t=time.monotonic(), source=source, phase=self._phase)
+        )
         return arr.nbytes
 
     def ensure_all(self) -> int:
@@ -987,23 +989,21 @@ class TieredParams:
             return 0
         nbytes = arr.nbytes
         charge = self.unit_charge(key, nbytes)
-        host = jnp.asarray(arr, dtype=self._flat[unit.path].dtype)
-        if self.arbiter is not None:
-            self.arbiter.make_room(self, charge)
-        with self._lock:
-            if self.residency.state_of(key) != LOADING:
-                return 0
-            self.residency.advance_clock()
-            self._evict_to_fit(charge)
-            t0 = time.perf_counter()
-            self._install(unit, host)
-            upload_s = time.perf_counter() - t0
-            self.residency.commit_load(key, charge, "prefetch")
-            self.stats.events.append(
-                LoadEvent(key, nbytes, fetch_s, upload_s,
-                          t=time.monotonic(), source="prefetch",
-                          phase=self._phase)
-            )
+        with span("repro.prefetch.install", source="prefetch", nbytes=nbytes) as inst:
+            host = jnp.asarray(arr, dtype=self._flat[unit.path].dtype)
+            if self.arbiter is not None:
+                self.arbiter.make_room(self, charge)
+            with self._lock:
+                if self.residency.state_of(key) != LOADING:
+                    return 0
+                self.residency.advance_clock()
+                self._evict_to_fit(charge)
+                self._install(unit, host)
+                self.residency.commit_load(key, charge, "prefetch")
+        self.stats.events.append(
+            LoadEvent(key, nbytes, fetch_s, inst.seconds,
+                      t=time.monotonic(), source="prefetch", phase=self._phase)
+        )
         return nbytes
 
     # -- eviction -------------------------------------------------------------

@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core.on_demand import TieredParams
 from repro.core.optional_store import COALESCE_GAP, ReadStats, StoreError
+from repro.utils.spans import span
 
 
 def merge_hints(*hint_lists: Iterable[str]) -> list[str]:
@@ -461,10 +462,11 @@ class Prefetcher:
             bufs: dict = {}
             rs = ReadStats()
             try:
-                t_read0 = time.perf_counter()
-                bufs = store.read_raw_many(
-                    ordered, gap_threshold=self.read_gap_bytes, stats=rs)
-                t_read = time.perf_counter() - t_read0
+                with span("repro.prefetch.read", source="prefetch") as read:
+                    bufs = store.read_raw_many(
+                        ordered, gap_threshold=self.read_gap_bytes, stats=rs)
+                    read.nbytes = sum(len(b) for b in bufs.values())
+                t_read = read.seconds
             except StoreError:
                 bufs, t_read = {}, 0.0
             self.stats.preads_issued += rs.preads
@@ -477,21 +479,23 @@ class Prefetcher:
                     self._done(1)
                     continue
                 try:
-                    t0 = time.perf_counter()
                     if key in bufs:
                         buf = bufs[key]
                         # amortize the batch read csize-proportionally so
                         # per-key fetch_s still sums to wall time spent
                         t_io = t_read * (store.entries[key].csize / total_csize)
                     else:
-                        t_io = 0.0
                         rs2 = ReadStats()
-                        buf = store.read_raw(key, stats=rs2)
+                        with span("repro.prefetch.read", source="prefetch") as one:
+                            buf = store.read_raw(key, stats=rs2)
+                            one.nbytes = len(buf)
+                        t_io = one.seconds
                         self.stats.preads_issued += rs2.preads
                         self.stats.frames_fetched += rs2.frames
-                    arr = store.decode(key, buf)
-                    stage.items.append(
-                        (key, arr, t_io + time.perf_counter() - t0))
+                    with span("repro.prefetch.decode", source="prefetch") as dec:
+                        arr = store.decode(key, buf)
+                        dec.nbytes = arr.nbytes
+                    stage.items.append((key, arr, t_io + dec.seconds))
                 except Exception:
                     self.stats.errors += 1
                     self.tiered.abort_prefetch(key)

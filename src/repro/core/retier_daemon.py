@@ -62,6 +62,7 @@ from repro.core.retier import (
     required_tier0,
     retier_artifact,
 )
+from repro.utils.spans import span
 
 
 @dataclass
@@ -198,7 +199,8 @@ class RetierDaemon:
     def _tick_absorbed(self) -> Optional[RetierReport]:
         t0 = time.monotonic()
         try:
-            return self._tick_locked()
+            with span("repro.retier.tick"):
+                return self._tick_locked()
         except Exception as e:  # degrade, don't kill the serving loop
             self.stats.errors += 1
             self.last_error = repr(e)
@@ -452,9 +454,10 @@ class RetierDaemon:
         t0 = time.monotonic()
         try:
             out = self.compact_out_dir or self.artifact_dir.rstrip("/") + "-compact"
-            meta = retier_artifact(
-                self.artifact_dir, plan, out_dir=out, report=report, trace=trace
-            )
+            with span("repro.retier.compact"):
+                meta = retier_artifact(
+                    self.artifact_dir, plan, out_dir=out, report=report, trace=trace
+                )
             with self._compact_lock:
                 self.stats.compactions += 1
                 self.last_compaction = meta

@@ -54,6 +54,7 @@ from repro.core.retier_daemon import RetierDaemon
 from repro.core import snapshot as server_snapshot
 from repro.models.zoo import Model
 from repro.sharding.rules import param_shardings, spec_shard_divisor
+from repro.utils.spans import span
 from repro.utils.tree import flatten_with_paths, tree_from_flat
 
 # residency policy -> (tier-1 budget fraction, prefetch enabled); DESIGN.md §4.2
@@ -66,12 +67,29 @@ RESIDENCY_PRESETS: dict = {
 
 @dataclass
 class ColdStartReport:
+    """The phases of one cold start, each timed by a span of the same
+    interval (``repro.utils.spans``): ``read_s`` (``repro.cold.read``),
+    ``upload_s`` (``repro.cold.upload``), ``compile_s``
+    (``repro.cold.compile``). After2's upload has three parts, one span
+    each: ``tier0_put_s`` puts tier-0 on the device; ``placeholder_s``
+    builds the tier-1 placeholders on the host, puts them, and waits for
+    every put of both; ``preload_s`` faults the hot set in.
+    ``bytes_uploaded`` counts every byte put on the device: tier-0,
+    ``placeholder_bytes`` (each tier-1 leaf at full shape), the preload's
+    and a restore's. ``t_start`` is the cold start's start on the span
+    clock (``time.perf_counter``)."""
+
     mode: str
     read_s: float = 0.0
     upload_s: float = 0.0
     compile_s: float = 0.0
     bytes_read: int = 0
     bytes_uploaded: int = 0
+    tier0_put_s: float = 0.0
+    placeholder_s: float = 0.0
+    placeholder_bytes: int = 0
+    preload_s: float = 0.0
+    t_start: float = 0.0
 
     @property
     def total_s(self) -> float:
@@ -86,6 +104,11 @@ class ColdStartReport:
             "total_s": self.total_s,
             "bytes_read": self.bytes_read,
             "bytes_uploaded": self.bytes_uploaded,
+            "tier0_put_s": self.tier0_put_s,
+            "placeholder_s": self.placeholder_s,
+            "placeholder_bytes": self.placeholder_bytes,
+            "preload_s": self.preload_s,
+            "t_start": self.t_start,
         }
 
 
@@ -257,7 +280,7 @@ def cold_start(
         raise ValueError(f"unknown residency policy {residency!r}; want one of {sorted(RESIDENCY_PRESETS)}")
     if restore_from is not None and mode != "after2":
         raise ValueError("restore_from= is after2-only (monolithic modes have no residency set)")
-    report = ColdStartReport(mode=mode)
+    report = ColdStartReport(mode=mode, t_start=time.perf_counter())
     abstract = model.abstract()
 
     # path-aware device placement: an explicit put= wins; else a mesh
@@ -287,20 +310,20 @@ def cold_start(
 
     if mode in ("before", "after1"):
         prefix = os.path.join(artifact_dir, mode)
-        t0 = time.perf_counter()
-        flat = tsl.read_bundle(prefix, mmap=False)  # move all bytes
-        report.bytes_read = sum(v.nbytes for v in flat.values())
-        t1 = time.perf_counter()
-        # upload the params collection only (other collections have no
-        # device-side consumer at serving time, but their bytes were read)
-        pflat = {
-            p[len("params."):]: v for p, v in flat.items() if p.startswith("params.")
-        }
-        tree = tree_from_flat({p: _put(p, v) for p, v in pflat.items()})
-        _block_until_ready(tree)
-        t2 = time.perf_counter()
-        report.read_s, report.upload_s = t1 - t0, t2 - t1
-        report.bytes_uploaded = sum(v.nbytes for v in pflat.values())
+        with span("repro.cold.read") as read:
+            flat = tsl.read_bundle(prefix, mmap=False)  # move all bytes
+            report.bytes_read = read.nbytes = sum(v.nbytes for v in flat.values())
+        with span("repro.cold.upload") as upload:
+            # upload the params collection only (other collections have no
+            # device-side consumer at serving time, but their bytes were read)
+            pflat = {
+                p[len("params."):]: v for p, v in flat.items() if p.startswith("params.")
+            }
+            tree = tree_from_flat({p: _put(p, v) for p, v in pflat.items()})
+            _block_until_ready(tree)
+            upload.nbytes = sum(v.nbytes for v in pflat.values())
+        report.read_s, report.upload_s = read.seconds, upload.seconds
+        report.bytes_uploaded = upload.nbytes
         server = ColdStartServer(model, tree, report,
                                  artifact_dir=artifact_dir, admission=admission,
                                  kv_page_size=kv_page_size, kv_pages=kv_pages)
@@ -308,77 +331,87 @@ def cold_start(
         if result is None:
             raise ValueError("after2 cold start needs the AnalysisResult (plan)")
         plan = result.plan
-        t0 = time.perf_counter()
-        tier0 = tsl.read_bundle(os.path.join(artifact_dir, "tier0"), mmap=False)
-        store = OptionalStore(os.path.join(artifact_dir, "optional.blob"))
-        report.bytes_read = sum(v.nbytes for v in tier0.values())
-        t1 = time.perf_counter()
-        flat_abs = dict(flatten_with_paths(abstract))
-        live_flat = {}
-        for path, leaf in flat_abs.items():
-            if plan.decisions[path].tier == 0:
-                live_flat[path] = _put(path, tier0[path])
-            else:
-                # the rewritten stub: placeholder zeros, full shape/sharding
-                live_flat[path] = _put(path, np.zeros(leaf.shape, leaf.dtype))
-        tree = tree_from_flat(live_flat)
-        _block_until_ready(tree)
-        # per-leaf shard counts for residency accounting (DESIGN.md §15.1):
-        # a unit of a D-way-sharded leaf costs nbytes/D per device
-        shard_divisors = None
-        if shardings_flat is not None:
-            shard_divisors = {
-                path: spec_shard_divisor(shardings_flat[path].spec, mesh)
-                for path in flat_abs
-            }
-        # resolve the residency preset into a budget + prefetch default —
-        # or, under a host arbiter, into a relative SHARE of its budget
-        budget = device_budget_bytes
-        want_prefetch = prefetch
-        share = tenant_share
-        if residency is not None:
-            frac, preset_prefetch = RESIDENCY_PRESETS[residency]
+        with span("repro.cold.read") as read:
+            tier0 = tsl.read_bundle(os.path.join(artifact_dir, "tier0"), mmap=False)
+            store = OptionalStore(os.path.join(artifact_dir, "optional.blob"))
+            report.bytes_read = read.nbytes = sum(v.nbytes for v in tier0.values())
+        with span("repro.cold.upload") as upload:
+            flat_abs = dict(flatten_with_paths(abstract))
+            live_flat = dict.fromkeys(flat_abs)
+            with span("repro.cold.tier0_put") as put0:
+                for path in flat_abs:
+                    if plan.decisions[path].tier == 0:
+                        live_flat[path] = _put(path, tier0[path])
+                        put0.nbytes += tier0[path].nbytes
+            with span("repro.cold.placeholder") as placeholder:
+                for path, leaf in flat_abs.items():
+                    if plan.decisions[path].tier != 0:
+                        # the rewritten stub: placeholder zeros, full shape/sharding
+                        host = np.zeros(leaf.shape, leaf.dtype)
+                        live_flat[path] = _put(path, host)
+                        placeholder.nbytes += host.nbytes
+                tree = tree_from_flat(live_flat)
+                with span("repro.cold.put_wait"):  # every put above, tier-0's too
+                    _block_until_ready(tree)
+            # per-leaf shard counts for residency accounting (DESIGN.md §15.1):
+            # a unit of a D-way-sharded leaf costs nbytes/D per device
+            shard_divisors = None
+            if shardings_flat is not None:
+                shard_divisors = {
+                    path: spec_shard_divisor(shardings_flat[path].spec, mesh)
+                    for path in flat_abs
+                }
+            # resolve the residency preset into a budget + prefetch default —
+            # or, under a host arbiter, into a relative SHARE of its budget
+            budget = device_budget_bytes
+            want_prefetch = prefetch
+            share = tenant_share
+            if residency is not None:
+                frac, preset_prefetch = RESIDENCY_PRESETS[residency]
+                if host_arbiter is not None:
+                    if share is None:
+                        share = frac if frac is not None else 1.0
+                elif budget is None and frac is not None:
+                    # budget fractions apply to *charged* (per-device) tier-1
+                    # bytes: under a mesh each leaf counts nbytes/divisor, so
+                    # the same preset means the same per-device pressure
+                    tier1_charged = plan.tier1_bytes
+                    if shard_divisors:
+                        tier1_charged = 0
+                        for path, leaf in flat_abs.items():
+                            if plan.decisions[path].tier != 0:
+                                nb = int(np.prod(leaf.shape, dtype=np.int64)) * np.dtype(leaf.dtype).itemsize
+                                d = shard_divisors.get(path, 1)
+                                tier1_charged += nb if d <= 1 else -(-nb // d)
+                    budget = int(frac * tier1_charged)
+                    # keep the machine functional: never below two of the
+                    # largest units (one incoming + one pinned)
+                    max_unit = max((e.rsize for e in store.entries.values()), default=0)
+                    budget = max(budget, 2 * max_unit)
+                if want_prefetch is None:
+                    want_prefetch = preset_prefetch
+            tiered = TieredParams(tree, plan, store, device_budget_bytes=budget,
+                                  shard_divisors=shard_divisors)
             if host_arbiter is not None:
-                if share is None:
-                    share = frac if frac is not None else 1.0
-            elif budget is None and frac is not None:
-                # budget fractions apply to *charged* (per-device) tier-1
-                # bytes: under a mesh each leaf counts nbytes/divisor, so
-                # the same preset means the same per-device pressure
-                tier1_charged = plan.tier1_bytes
-                if shard_divisors:
-                    tier1_charged = 0
-                    for path, leaf in flat_abs.items():
-                        if plan.decisions[path].tier != 0:
-                            nb = int(np.prod(leaf.shape, dtype=np.int64)) * np.dtype(leaf.dtype).itemsize
-                            d = shard_divisors.get(path, 1)
-                            tier1_charged += nb if d <= 1 else -(-nb // d)
-                budget = int(frac * tier1_charged)
-                # keep the machine functional: never below two of the
-                # largest units (one incoming + one pinned)
-                max_unit = max((e.rsize for e in store.entries.values()), default=0)
-                budget = max(budget, 2 * max_unit)
-            if want_prefetch is None:
-                want_prefetch = preset_prefetch
-        tiered = TieredParams(tree, plan, store, device_budget_bytes=budget,
-                              shard_divisors=shard_divisors)
-        if host_arbiter is not None:
-            # join the host pool BEFORE the hot preload so even cold-start
-            # bytes are admitted by the global make-room path
-            name = tenant_name or getattr(model.cfg, "name", "") or f"tenant-{id(tiered):x}"
-            host_arbiter.register(
-                name, tiered,
-                share=share if share is not None else 1.0,
-                floor_bytes=tenant_floor_bytes,
-            )
-        if trace or retier_online:  # the daemon needs a live trace to watch
-            tiered.start_trace(AccessTrace())
-        # preload the hot set (the paper's offline-profiled module-init list)
-        hot = [k for d in plan.decisions.values() for k in d.resident_units]
-        moved = tiered.ensure(hot, source="preload") if hot else 0
-        t2 = time.perf_counter()
-        report.read_s, report.upload_s = t1 - t0, t2 - t1
-        report.bytes_uploaded = report.bytes_read + moved
+                # join the host pool BEFORE the hot preload so even cold-start
+                # bytes are admitted by the global make-room path
+                name = tenant_name or getattr(model.cfg, "name", "") or f"tenant-{id(tiered):x}"
+                host_arbiter.register(
+                    name, tiered,
+                    share=share if share is not None else 1.0,
+                    floor_bytes=tenant_floor_bytes,
+                )
+            if trace or retier_online:  # the daemon needs a live trace to watch
+                tiered.start_trace(AccessTrace())
+            # preload the hot set (the paper's offline-profiled module-init list)
+            hot = [k for d in plan.decisions.values() for k in d.resident_units]
+            with span("repro.cold.preload") as preload:
+                preload.nbytes = tiered.ensure(hot, source="preload") if hot else 0
+        report.read_s, report.upload_s = read.seconds, upload.seconds
+        report.tier0_put_s, report.placeholder_s = put0.seconds, placeholder.seconds
+        report.preload_s = preload.seconds
+        report.placeholder_bytes = placeholder.nbytes
+        report.bytes_uploaded = put0.nbytes + placeholder.nbytes + preload.nbytes
         prefetcher = (
             Prefetcher(tiered, batch_units=prefetch_batch_units, predictor=predictor)
             if want_prefetch
@@ -409,33 +442,33 @@ def cold_start(
             # set (in LRU order, through the arbiter make-room path) and arm
             # the predictor BEFORE the server admits traffic. Counted in the
             # upload phase — it is bytes moved as part of becoming ready.
-            t_r = time.perf_counter()
-            snap = (
-                server_snapshot.load(restore_from)
-                if isinstance(restore_from, str) else restore_from
-            )
-            server.restore_report = server_snapshot.restore(
-                tiered, snap, prefetcher=prefetcher, artifact_dir=artifact_dir
-            )
-            report.upload_s += time.perf_counter() - t_r
+            with span("repro.cold.restore") as restore:
+                snap = (
+                    server_snapshot.load(restore_from)
+                    if isinstance(restore_from, str) else restore_from
+                )
+                server.restore_report = server_snapshot.restore(
+                    tiered, snap, prefetcher=prefetcher, artifact_dir=artifact_dir
+                )
+            report.upload_s += restore.seconds
             report.bytes_uploaded += server.restore_report.get("moved_bytes", 0)
     else:
         raise ValueError(f"unknown mode {mode!r}")
 
     if compile_warm_set:
-        t3 = time.perf_counter()
-        p = server.live_params()
-        for B, S in warm_shapes:
-            pb, _ = model.prefill_batch_spec(B, S, multimodal=False)
-            pb.pop("frames", None)
-            pb.pop("image_embeds", None)
-            fn = server.compiled_prefill(B, S)
-            _ = fn.lower(p, _zeros_batch(pb)).compile()
-            dfn = server.compiled_decode(B)
-            cache = model.abstract_cache(B, S, multimodal=False)
-            db, _ = model.decode_batch_spec(B)
-            _ = dfn.lower(p, _abs_zeros(cache), _zeros_batch(db)).compile()
-        report.compile_s = time.perf_counter() - t3
+        with span("repro.cold.compile") as compile_:
+            p = server.live_params()
+            for B, S in warm_shapes:
+                pb, _ = model.prefill_batch_spec(B, S, multimodal=False)
+                pb.pop("frames", None)
+                pb.pop("image_embeds", None)
+                fn = server.compiled_prefill(B, S)
+                _ = fn.lower(p, _zeros_batch(pb)).compile()
+                dfn = server.compiled_decode(B)
+                cache = model.abstract_cache(B, S, multimodal=False)
+                db, _ = model.decode_batch_spec(B)
+                _ = dfn.lower(p, _abs_zeros(cache), _zeros_batch(db)).compile()
+        report.compile_s = compile_.seconds
     return server
 
 

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.cold_start import ColdStartServer
+from repro.utils.spans import span
 from repro.utils.tree import flatten_with_paths
 
 MAX_FAULT_RETRIES = 3
@@ -152,15 +153,15 @@ class GenerationEngine:
         needed = self.row_keys_for(tokens)
         n_cold = sum(1 for k in needed if not tiered.is_resident(k))
         pins.extend(needed)
-        t0 = time.perf_counter()
-        moved = tiered.ensure(needed, pin=True)
-        stats.fault_s += time.perf_counter() - t0
+        with span("repro.engine.row_ensure") as ens:
+            moved = ens.nbytes = tiered.ensure(needed, pin=True)
+        stats.fault_s += ens.seconds
         stats.faulted_bytes += moved
         stats.faulted_units += n_cold  # incl. waits on in-flight prefetch
         return needed
 
     def _fault_experts(
-        self, caches: Any, stats: RequestStats, pins: list
+        self, caches: Any, stats: RequestStats, pins: list, wait: str
     ) -> tuple[list[str], list[str]]:
         """Ensure (and pin) every expert the last step routed to — resident
         experts included: their demand touches keep the access trace honest
@@ -169,18 +170,22 @@ class GenerationEngine:
         ``(newly_faulted, used)``: retry is needed only while the first is
         nonempty, while hints/predictor observations want the second (a
         warm expert is still the strongest predictor of the next step);
-        pins are registered before the load, as in ``_prefault_rows``."""
+        pins are registered before the load, as in ``_prefault_rows``.
+        Pulling the usage masks is the host's first wait on the step's
+        outputs: a span named ``wait``."""
         tiered = self.server.tiered
         if tiered is None:
             return [], []
-        used = self._expert_keys_from_usage(_usage_masks(caches))
+        with span(wait):
+            usage = _usage_masks(caches)
+        used = self._expert_keys_from_usage(usage)
         if not used:
             return [], []
         miss = [k for k in used if not tiered.is_resident(k)]
         pins.extend(used)
-        t0 = time.perf_counter()
-        moved = tiered.ensure(used, pin=True)
-        stats.fault_s += time.perf_counter() - t0
+        with span("repro.engine.expert_ensure") as ens:
+            moved = ens.nbytes = tiered.ensure(used, pin=True)
+        stats.fault_s += ens.seconds
         stats.faulted_bytes += moved
         stats.faulted_units += len(miss)
         return miss, used
@@ -239,24 +244,29 @@ class GenerationEngine:
             fault0 = stats.fault_s
             t0 = time.perf_counter()
             batch = {"tokens": tokens}
-            logits, caches = prefill(server.live_params(), batch)
+            with span("repro.engine.prefill_dispatch"):
+                logits, caches = prefill(server.live_params(), batch)
             for _ in range(MAX_FAULT_RETRIES):
-                newly, used = self._fault_experts(caches, stats, step_pins)
+                newly, used = self._fault_experts(caches, stats, step_pins,
+                                                  "repro.engine.prefill_wait")
                 seen = set(expert_keys)
                 expert_keys.extend(k for k in used if k not in seen)
                 if not newly:
                     break
                 stats.prefill_retries += 1
-                logits, caches = prefill(server.live_params(), batch)
-            jax.block_until_ready(logits)
+                with span("repro.engine.prefill_dispatch"):
+                    logits, caches = prefill(server.live_params(), batch)
+            with span("repro.engine.prefill_wait"):
+                jax.block_until_ready(logits)
             stats.prefill_s += time.perf_counter() - t0 - (stats.fault_s - fault0)
         finally:
             if tiered is not None and step_pins:
                 tiered.release(step_pins)
         # hint after release: evicted/still-cold predictions are loadable now
         if hint:
-            self._hint_next_step(logits, expert_keys, stats,
-                                 accessed=accessed + expert_keys)
+            with span("repro.engine.hints"):
+                self._hint_next_step(logits, expert_keys, stats,
+                                     accessed=accessed + expert_keys)
         return logits, _strip_usage(caches), expert_keys
 
     def decode_once(
@@ -287,23 +297,28 @@ class GenerationEngine:
             accessed += self._prefault_rows(np.asarray(prefault_tokens), stats, step_pins)
             fault0 = stats.fault_s
             t0 = time.perf_counter()
-            logits, new_caches = decode_fn(server.live_params(), caches, dbatch)
+            with span("repro.engine.decode_dispatch"):
+                logits, new_caches = decode_fn(server.live_params(), caches, dbatch)
             for _ in range(MAX_FAULT_RETRIES):
-                newly, used = self._fault_experts(new_caches, stats, step_pins)
+                newly, used = self._fault_experts(new_caches, stats, step_pins,
+                                                  "repro.engine.decode_wait")
                 seen = set(expert_keys)
                 expert_keys.extend(k for k in used if k not in seen)
                 if not newly:
                     break
                 stats.decode_retries += 1
-                logits, new_caches = decode_fn(server.live_params(), caches, dbatch)
-            jax.block_until_ready(logits)
+                with span("repro.engine.decode_dispatch"):
+                    logits, new_caches = decode_fn(server.live_params(), caches, dbatch)
+            with span("repro.engine.decode_wait"):
+                jax.block_until_ready(logits)
             stats.decode_s += time.perf_counter() - t0 - (stats.fault_s - fault0)
         finally:
             if tiered is not None and step_pins:
                 tiered.release(step_pins)
         if hint:
-            self._hint_next_step(logits, expert_keys, stats,
-                                 accessed=accessed + expert_keys)
+            with span("repro.engine.hints"):
+                self._hint_next_step(logits, expert_keys, stats,
+                                     accessed=accessed + expert_keys)
         return logits, _strip_usage(new_caches), expert_keys
 
     # -- request path -----------------------------------------------------------

@@ -60,6 +60,7 @@ import numpy as np
 from repro.core.prefetch import merge_hints
 from repro.serving.engine import GenerationEngine, RequestStats
 from repro.serving.paged_kv import PagePool
+from repro.utils.spans import span
 from repro.utils.tree import flatten_with_paths, tree_from_flat
 
 
@@ -440,13 +441,10 @@ class ContinuousBatchingScheduler:
         return None
 
     # -- admission ---------------------------------------------------------------
-    def _admit(self) -> int:
-        """Fill free slots per the admission policy (FIFO by default).
-        Same-length prompts admitted in the same round share ONE batched
-        prefill (the step primitives are batch-agnostic, so their
-        vocab/expert faults union for free); the resulting cache rows are
-        grafted into the slots in a single jitted call. Returns the
-        number of requests admitted."""
+    def _select(self) -> dict[int, list[tuple[int, Request]]]:
+        """One admission round's policy select and page grants: the
+        requests to admit, as ``(slot, request)`` groups by prompt length.
+        Dropped and ungranted requests are retired here."""
         free = [i for i, r in enumerate(self._slots) if r is None]
         to_admit, dropped = self.admission.select(
             self.queue, len(free), time.perf_counter(), self._validate
@@ -476,17 +474,28 @@ class ContinuousBatchingScheduler:
                 ))
                 continue
             granted.append((slot, req))
-        picked = granted
+        # group same-length prompts (everything picked is admitted this
+        # round, so grouping cannot reorder anyone past anyone else)
+        groups: dict[int, list[tuple[int, Request]]] = {}
+        for slot, req in granted:
+            groups.setdefault(req.tokens.size, []).append((slot, req))
+        return groups
 
+    def _admit(self) -> int:
+        """Fill free slots per the admission policy (FIFO by default).
+        Same-length prompts admitted in the same round share ONE batched
+        prefill (the step primitives are batch-agnostic, so their
+        vocab/expert faults union for free); the resulting cache rows are
+        grafted into the slots in a single jitted call. Returns the
+        number of requests admitted."""
+        with span("repro.sched.admit"):
+            groups = self._select()
+        if not groups:
+            return 0
         admitted = 0
         hints: list[list[str]] = []
         observed: list[str] = []
         by_request: dict[int, list[str]] = {}
-        # group same-length prompts (everything picked is admitted this
-        # round, so grouping cannot reorder anyone past anyone else)
-        groups: dict[int, list[tuple[int, Request]]] = {}
-        for slot, req in picked:
-            groups.setdefault(req.tokens.size, []).append((slot, req))
         for S, grp in groups.items():
             slots = [s for s, _ in grp]
             reqs = [r for _, r in grp]
@@ -496,9 +505,11 @@ class ContinuousBatchingScheduler:
             shared = RequestStats()
             try:
                 toks = jnp.asarray(np.stack([r.tokens for r in reqs]))
-                logits, caches, expert_keys = self.engine.prefill_step(
-                    toks, shared, hint=False
-                )
+                with span("repro.sched.prefill",
+                          rid=reqs[0].rid if len(reqs) == 1 else None):
+                    logits, caches, expert_keys = self.engine.prefill_step(
+                        toks, shared, hint=False
+                    )
             except Exception as e:
                 # a failed fault-in must not kill the loop (or leave the
                 # submitters waiting forever) — fail the group's requests,
@@ -509,42 +520,46 @@ class ContinuousBatchingScheduler:
                     r.finish(error=f"prefill failed: {e!r}")
                 continue
             self.admission.note_prefill(shared.prefill_s + shared.fault_s)
-            self._caches = self._graft(self._caches, caches, jnp.asarray(slots, jnp.int32))
-            lg = np.asarray(logits)
-            # per-request attribution (§12.3): each prompt's own row-groups;
-            # expert keys are exact only when the prefill wasn't shared.
-            # Skipped entirely when no trace is attached — the common
-            # tracing-off path pays nothing for it.
-            if self._tracing:
-                for r in reqs:
-                    by_request[r.rid] = self.engine.row_keys_for(r.tokens) + (
-                        list(expert_keys) if len(reqs) == 1 else []
-                    )
-            for i, (slot, req) in enumerate(grp):
-                # group costs are shared: every member waited out the batch
-                req.stats.prefill_s += shared.prefill_s
-                req.stats.fault_s += shared.fault_s
-                req.stats.prefill_retries += shared.prefill_retries
-                req.stats.faulted_units += shared.faulted_units
-                req.stats.faulted_bytes += shared.faulted_bytes
-                tok = int(lg[i].argmax())
-                req.out.append(tok)
-                req.stats.steps = 1  # the prefill-produced token
-                req.first_token_t = time.perf_counter()
-                self._pos[slot] = S
-                self._last_tok[slot] = tok
-                self._slots[slot] = req
-                self.stats.admitted += 1
-                admitted += 1
-                hints.append(self.engine.topk_row_hints(lg[i]))
-                if len(req.out) >= req.n_steps:  # single-token request
-                    self._retire(slot)
-            if expert_keys:
-                hints.append(list(expert_keys))
-            observed += self.engine.row_keys_for(
-                np.concatenate([r.tokens for r in reqs])
-            ) + list(expert_keys)
-        self._emit_hints(hints, observed=observed, by_request=by_request)
+            with span("repro.sched.graft"):
+                self._caches = self._graft(self._caches, caches, jnp.asarray(slots, jnp.int32))
+            with span("repro.sched.tokens"):
+                lg = np.asarray(logits)
+                # per-request attribution (§12.3): each prompt's own row-groups;
+                # expert keys are exact only when the prefill wasn't shared.
+                # Skipped entirely when no trace is attached — the common
+                # tracing-off path pays nothing for it.
+                if self._tracing:
+                    for r in reqs:
+                        by_request[r.rid] = self.engine.row_keys_for(r.tokens) + (
+                            list(expert_keys) if len(reqs) == 1 else []
+                        )
+                for i, (slot, req) in enumerate(grp):
+                    # group costs are shared: every member waited out the batch
+                    req.stats.prefill_s += shared.prefill_s
+                    req.stats.fault_s += shared.fault_s
+                    req.stats.prefill_retries += shared.prefill_retries
+                    req.stats.faulted_units += shared.faulted_units
+                    req.stats.faulted_bytes += shared.faulted_bytes
+                    tok = int(lg[i].argmax())
+                    req.out.append(tok)
+                    req.stats.steps = 1  # the prefill-produced token
+                    req.first_token_t = time.perf_counter()
+                    self._pos[slot] = S
+                    self._last_tok[slot] = tok
+                    self._slots[slot] = req
+                    self.stats.admitted += 1
+                    admitted += 1
+                    if len(req.out) >= req.n_steps:  # single-token request
+                        self._retire(slot)
+            with span("repro.sched.hints"):
+                hints.extend(self.engine.topk_row_hints(lg[i]) for i in range(len(grp)))
+                if expert_keys:
+                    hints.append(list(expert_keys))
+                observed += self.engine.row_keys_for(
+                    np.concatenate([r.tokens for r in reqs])
+                ) + list(expert_keys)
+        with span("repro.sched.hints"):
+            self._emit_hints(hints, observed=observed, by_request=by_request)
         return admitted
 
     def _retire(self, slot: int) -> None:
@@ -595,12 +610,19 @@ class ContinuousBatchingScheduler:
         """Admit new work, then advance every active slot one token with a
         single masked decode over the union of their faults. Returns True
         if anything happened (admission or decode)."""
+        if self.idle:
+            # still a step boundary: the re-tier daemon may tick on
+            # wall-clock cadence even while the queue is drained (§12)
+            self.engine.tick_retier(steps=0)
+            return False
+        with span("repro.sched.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         admitted = self._admit()
         active = self.active
         self.stats.max_active = max(self.stats.max_active, len(active))
         if not active:
-            # still a step boundary: the re-tier daemon may tick on
-            # wall-clock cadence even while the queue is drained (§12)
             self.engine.tick_retier(steps=0)
             return admitted > 0
 
@@ -638,53 +660,56 @@ class ContinuousBatchingScheduler:
                     tiered.end_request(req.rid)
                 req.finish(error=f"decode step failed: {e!r}")
             return True
-        self.stats.decode_s += step_stats.decode_s
-        self.stats.fault_s += step_stats.fault_s
-        self.stats.faulted_units += step_stats.faulted_units
-        self.stats.faulted_bytes += step_stats.faulted_bytes
-        self.stats.decode_retries += step_stats.decode_retries
-        self.stats.steps += 1
-        self.admission.note_step(step_stats.decode_s + step_stats.fault_s, len(active))
-        # paged-KV accounting (§16.2): the masked decode streams the full
-        # (max_batch, max_seq) cache; the paged layout would stream only
-        # the active slots' occupied pages. The roofline gate compares.
-        self.stats.kv_tokens_dense += self.max_batch * self.engine.max_seq
-        self.stats.kv_tokens_paged += self.page_pool.step_kv_positions(
-            {i: int(self._pos[i]) + 1 for i in active}
-        )
-        self.stats.kv_pages_high_water = self.page_pool.stats.high_water_pages
-
-        # units this step demand-accessed: the active slots' embed
-        # row-groups plus every routed expert (resident ones included —
-        # post-retier they key most of the transition table)
-        observed = self.engine.row_keys_for(self._last_tok[active]) + list(expert_keys)
-        # per-request attribution (§12.3), captured before the token
-        # updates below overwrite _last_tok: each slot's own row-groups;
-        # union-detected experts are exact only with a single active slot.
-        # Skipped when no trace is attached (nothing would record it).
-        by_request = {
-            self._slots[i].rid: self.engine.row_keys_for(self._last_tok[i:i + 1]) + (
-                list(expert_keys) if len(active) == 1 else []
+        with span("repro.sched.tokens"):
+            self.stats.decode_s += step_stats.decode_s
+            self.stats.fault_s += step_stats.fault_s
+            self.stats.faulted_units += step_stats.faulted_units
+            self.stats.faulted_bytes += step_stats.faulted_bytes
+            self.stats.decode_retries += step_stats.decode_retries
+            self.stats.steps += 1
+            self.admission.note_step(step_stats.decode_s + step_stats.fault_s, len(active))
+            # paged-KV accounting (§16.2): the masked decode streams the full
+            # (max_batch, max_seq) cache; the paged layout would stream only
+            # the active slots' occupied pages. The roofline gate compares.
+            self.stats.kv_tokens_dense += self.max_batch * self.engine.max_seq
+            self.stats.kv_tokens_paged += self.page_pool.step_kv_positions(
+                {i: int(self._pos[i]) + 1 for i in active}
             )
-            for i in active
-        } if self._tracing else {}
+            self.stats.kv_pages_high_water = self.page_pool.stats.high_water_pages
 
-        lg = np.asarray(logits)
-        hints: list[list[str]] = []
-        for i in active:
-            req = self._slots[i]
-            tok = int(lg[i].argmax())
-            req.out.append(tok)
-            req.stats.steps += 1
-            self._last_tok[i] = tok
-            self._pos[i] += 1
-            if len(req.out) >= req.n_steps:
-                self._retire(i)
-            else:
-                hints.append(self.engine.topk_row_hints(lg[i]))
-        if expert_keys:
-            hints.append(list(expert_keys))
-        self._emit_hints(hints, observed=observed, by_request=by_request)
+            # units this step demand-accessed: the active slots' embed
+            # row-groups plus every routed expert (resident ones included —
+            # post-retier they key most of the transition table)
+            observed = self.engine.row_keys_for(self._last_tok[active]) + list(expert_keys)
+            # per-request attribution (§12.3), captured before the token
+            # updates below overwrite _last_tok: each slot's own row-groups;
+            # union-detected experts are exact only with a single active slot.
+            # Skipped when no trace is attached (nothing would record it).
+            by_request = {
+                self._slots[i].rid: self.engine.row_keys_for(self._last_tok[i:i + 1]) + (
+                    list(expert_keys) if len(active) == 1 else []
+                )
+                for i in active
+            } if self._tracing else {}
+
+            lg = np.asarray(logits)
+            going: list[int] = []  # slots that decode again next step
+            for i in active:
+                req = self._slots[i]
+                tok = int(lg[i].argmax())
+                req.out.append(tok)
+                req.stats.steps += 1
+                self._last_tok[i] = tok
+                self._pos[i] += 1
+                if len(req.out) >= req.n_steps:
+                    self._retire(i)
+                else:
+                    going.append(i)
+        with span("repro.sched.hints"):
+            hints = [self.engine.topk_row_hints(lg[i]) for i in going]
+            if expert_keys:
+                hints.append(list(expert_keys))
+            self._emit_hints(hints, observed=observed, by_request=by_request)
         # the step is fully over (pins released, outputs materialized):
         # the ONLY place the serving loop advances the re-tier daemon
         self.engine.tick_retier()
