@@ -4,8 +4,9 @@ The paper rewrites each optional function to a 2-line stub that, on first
 invocation, reads the lightweight file, materializes the separated code, and
 executes it. Here the "stub" is a *placeholder buffer*: tier-1 leaves start
 as zero-filled device arrays (correctly sharded, so the compiled executable
-is identical to the fully-loaded one); the ``OnDemandLoader`` faults real
-bytes in unit-by-unit when requests need them.
+is identical to the fully-loaded one), allocated on the device by
+``device_zeros`` rather than copied from host zeros; the ``OnDemandLoader``
+faults real bytes in unit-by-unit when requests need them.
 
 Correctness backstop, as in the paper: a misprediction (cold expert routed
 to, cold vocab row sampled) is a *latency* event — fetch + decompress +
@@ -624,7 +625,8 @@ class TieredParams:
     """The live parameter tree of a cold-started server.
 
     * tier-0 leaves: real weights, device-resident from cold start.
-    * tier-1 leaves: allocated at full shape (placeholder zeros) and filled
+    * tier-1 leaves: allocated at full shape (placeholder zeros, made on the
+      device by ``device_zeros`` under the leaf's sharding) and filled
       in-place per unit (experts: ``at[e].set``; rows: row-slice scatter;
       whole-leaf: swap). Allocation is eager but *bytes* move lazily —
       device memory for tier-1 is the explicit rent paid for the identical
@@ -1073,7 +1075,7 @@ class TieredParams:
         """The eviction inverse of ``_install``: zero the unit's slice."""
         leaf = self._flat[unit.path]
         if not unit.sel and unit.rows is None:
-            new = jax.device_put(jnp.zeros(leaf.shape, leaf.dtype), self._leaf_sharding(leaf))
+            new = device_zeros(leaf.shape, leaf.dtype, self._leaf_sharding(leaf))
         elif unit.rows is not None:
             lo, hi = unit.rows
             new = leaf.at[unit.sel + (slice(lo, hi),)].set(0) if unit.sel else leaf.at[lo:hi].set(0)
@@ -1103,18 +1105,39 @@ class TieredParams:
         return self._flat[path]
 
 
-def placeholder_tree(abstract: Any, tier0: dict[str, np.ndarray], plan: TierPlan, put: Callable) -> dict:
+def device_zeros(shape: tuple, dtype: Any, sharding: Any = None) -> jax.Array:
+    """Zeros of ``shape``/``dtype`` allocated on the device under ``sharding``
+    (the default device when None): a tier-1 placeholder. No host bytes
+    cross to the device, and under a ``NamedSharding`` each device writes
+    its own shard, never the whole leaf."""
+    # A fresh program each call, dropped once it has run: a loaded program
+    # holds about 40 KB of device memory on a TPU v5e, so a cached one (or
+    # eager ``jnp.zeros(..., device=)``'s cached ops) would stay resident
+    # beside the weights for the life of the process.
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)()
+
+
+def placeholder_tree(
+    abstract: Any,
+    tier0: dict[str, np.ndarray],
+    plan: TierPlan,
+    put: Callable,
+    shardings: Optional[dict] = None,
+) -> dict:
     """Build the initial live tree: tier-0 leaves from real weights, tier-1
     leaves as placeholder zeros (identical shapes/shardings → identical
     compiled executable; the paper's rewritten function with an empty body).
 
-    ``put(path, host_array_or_none, leaf_spec)`` -> device array; the
-    cold-start manager passes a sharded device_put.
+    ``put(path, host_array, leaf_spec)`` -> device array puts each tier-0
+    leaf; the cold-start manager passes a sharded device_put. Tier-1 leaves
+    are ``device_zeros`` under ``shardings[path]`` (path -> sharding; the
+    default device where absent).
     """
+    shardings = shardings or {}
     out: dict[str, Any] = {}
     for path, leaf in flatten_with_paths(abstract):
         if plan.decisions[path].tier == 0:
             out[path] = put(path, tier0[path], leaf)
         else:
-            out[path] = put(path, None, leaf)
+            out[path] = device_zeros(leaf.shape, leaf.dtype, shardings.get(path))
     return tree_from_flat(out)

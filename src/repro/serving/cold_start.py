@@ -4,7 +4,8 @@ three variants measured end to end.
 Phases mirror Fig. 1 of the paper, adapted per DESIGN.md §2:
 
   read    — storage → host RAM (the paper's "application transmission")
-  upload  — host → device + placeholder allocation ("code loading", part 1)
+  upload  — host → device + on-device placeholder allocation ("code
+            loading", part 1)
   compile — XLA compilation of the warm entry set ("code loading", part 2 —
             the interpreter-import analogue)
 
@@ -47,7 +48,7 @@ import numpy as np
 from repro.checkpoint import tensorstore_lite as tsl
 from repro.core.analyzer import AnalysisResult
 from repro.core.arbiter import HostArbiter
-from repro.core.on_demand import AccessTrace, TieredParams
+from repro.core.on_demand import AccessTrace, TieredParams, device_zeros
 from repro.core.optional_store import OptionalStore
 from repro.core.prefetch import Prefetcher, TransitionPredictor
 from repro.core.retier_daemon import RetierDaemon
@@ -55,7 +56,7 @@ from repro.core import snapshot as server_snapshot
 from repro.models.zoo import Model
 from repro.sharding.rules import param_shardings, spec_shard_divisor
 from repro.utils.spans import span
-from repro.utils.tree import flatten_with_paths, tree_from_flat
+from repro.utils.tree import flatten_with_paths, tree_bytes, tree_from_flat
 
 # residency policy -> (tier-1 budget fraction, prefetch enabled); DESIGN.md §4.2
 RESIDENCY_PRESETS: dict = {
@@ -72,12 +73,15 @@ class ColdStartReport:
     ``upload_s`` (``repro.cold.upload``), ``compile_s``
     (``repro.cold.compile``). After2's upload has three parts, one span
     each: ``tier0_put_s`` puts tier-0 on the device; ``placeholder_s``
-    builds the tier-1 placeholders on the host, puts them, and waits for
-    every put of both; ``preload_s`` faults the hot set in.
-    ``bytes_uploaded`` counts every byte put on the device: tier-0,
-    ``placeholder_bytes`` (each tier-1 leaf at full shape), the preload's
-    and a restore's. ``t_start`` is the cold start's start on the span
-    clock (``time.perf_counter``)."""
+    allocates the tier-1 placeholders (zeros on the device, under each
+    leaf's sharding) and waits for every put, tier-0's included;
+    ``preload_s`` faults the hot set in. ``placeholder_bytes`` is the
+    tier-1 bytes allocated at full shape; ``placeholder_host_bytes`` the
+    part of them that crossed from the host: 0, except under a ``put=``
+    override, whose function takes host arrays and so is given host zeros.
+    ``bytes_uploaded`` counts the bytes that crossed to the device: tier-0,
+    ``placeholder_host_bytes``, the preload's and a restore's. ``t_start``
+    is the cold start's start on the span clock (``time.perf_counter``)."""
 
     mode: str
     read_s: float = 0.0
@@ -88,6 +92,7 @@ class ColdStartReport:
     tier0_put_s: float = 0.0
     placeholder_s: float = 0.0
     placeholder_bytes: int = 0
+    placeholder_host_bytes: int = 0
     preload_s: float = 0.0
     t_start: float = 0.0
 
@@ -107,6 +112,7 @@ class ColdStartReport:
             "tier0_put_s": self.tier0_put_s,
             "placeholder_s": self.placeholder_s,
             "placeholder_bytes": self.placeholder_bytes,
+            "placeholder_host_bytes": self.placeholder_host_bytes,
             "preload_s": self.preload_s,
             "t_start": self.t_start,
         }
@@ -269,8 +275,9 @@ def cold_start(
     All are after2-only and ignored for the monolithic baselines.
 
     ``mesh=`` threads a jax Mesh through every device_put: tier-0 leaves
-    and tier-1 placeholders land as *shards* resolved via the logical-axis
-    rules (repro.sharding), and the residency budget/arbiter charge
+    land as *shards* resolved via the logical-axis rules
+    (repro.sharding), tier-1 placeholders are allocated on the devices
+    under the same shardings, and the residency budget/arbiter charge
     per-device bytes (nbytes / shard count) instead of replicated bytes
     (DESIGN.md §15.1). ``restore_from=`` (a snapshot dict or JSON path)
     re-faults a previously-warmed server's residency set and arms its
@@ -296,17 +303,22 @@ def cold_start(
                 )
             )
         )
+    # A tier-1 placeholder is zeros allocated on the device under the same
+    # sharding; only the put= override, whose function takes host arrays,
+    # is given host zeros to put.
     if put is not None:
         user_put = put
         def _put(path, host):
             return user_put(host)
-    elif shardings_flat is not None:
-        def _put(path, host):
-            sh = shardings_flat.get(path)
-            return jax.device_put(host, sh) if sh is not None else jax.device_put(host)
+        def _placeholder(path, leaf):
+            return user_put(np.zeros(leaf.shape, leaf.dtype))
     else:
+        def _sharding(path):
+            return shardings_flat.get(path) if shardings_flat is not None else None
         def _put(path, host):
-            return jax.device_put(host)
+            return jax.device_put(host, _sharding(path))
+        def _placeholder(path, leaf):
+            return device_zeros(leaf.shape, leaf.dtype, _sharding(path))
 
     if mode in ("before", "after1"):
         prefix = os.path.join(artifact_dir, mode)
@@ -347,9 +359,8 @@ def cold_start(
                 for path, leaf in flat_abs.items():
                     if plan.decisions[path].tier != 0:
                         # the rewritten stub: placeholder zeros, full shape/sharding
-                        host = np.zeros(leaf.shape, leaf.dtype)
-                        live_flat[path] = _put(path, host)
-                        placeholder.nbytes += host.nbytes
+                        live_flat[path] = _placeholder(path, leaf)
+                        placeholder.nbytes += tree_bytes(leaf)
                 tree = tree_from_flat(live_flat)
                 with span("repro.cold.put_wait"):  # every put above, tier-0's too
                     _block_until_ready(tree)
@@ -411,7 +422,8 @@ def cold_start(
         report.tier0_put_s, report.placeholder_s = put0.seconds, placeholder.seconds
         report.preload_s = preload.seconds
         report.placeholder_bytes = placeholder.nbytes
-        report.bytes_uploaded = put0.nbytes + placeholder.nbytes + preload.nbytes
+        report.placeholder_host_bytes = placeholder.nbytes if put is not None else 0
+        report.bytes_uploaded = put0.nbytes + report.placeholder_host_bytes + preload.nbytes
         prefetcher = (
             Prefetcher(tiered, batch_units=prefetch_batch_units, predictor=predictor)
             if want_prefetch

@@ -138,9 +138,13 @@ def test_cold_start_upload_parts(tmp_path):
     assert moved > 0
     tier0 = sum(tree_bytes(leaf) for path, leaf in leaves.items()
                 if res.plan.decisions[path].tier == 0)
-    assert rep.bytes_uploaded == tier0 + tier1 + moved
+    # the placeholders are allocated on the device: only tier-0 and the
+    # preload's bytes cross from the host
+    assert rep.placeholder_host_bytes == 0
+    assert rep.bytes_uploaded == tier0 + moved
     d = rep.to_dict()
     assert d["placeholder_bytes"] == tier1 and d["t_start"] == rep.t_start
+    assert d["placeholder_host_bytes"] == 0
     assert rep.t_start <= time.perf_counter()
 
 
