@@ -70,6 +70,7 @@ class Record:
     requests: list = field(default_factory=list)   # dicts, see _request_rows
     steps: list = field(default_factory=list)      # Step, in the window
     cold: Optional[dict] = None                    # ColdStartReport of an in-window cold start
+    t_open: Optional[float] = None                 # the window's opening, time.perf_counter
     compiles: int = 0                              # programs compiled or loaded in the window
     lateness_s: list = field(default_factory=list)
     trace: Optional[dict] = None                   # bench.trace.reduce of the traced span
@@ -151,10 +152,12 @@ def _drop_page_cache(root: Path) -> None:
 
 def warm_groups(mix: dict, reqs: list) -> list[tuple[int, int]]:
     """(group size, prompt length) prefill shapes the window can admit:
-    every length at up to ``warm_group`` requests per admission, and at up
-    to as many as the first ``slots`` requests hold of it, since the first
-    admission round takes whichever of them are due by then."""
-    first = [len(r.prompt) for r in reqs[: mix["slots"]]]
+    every length at up to ``warm_group`` requests per admission. A cold
+    window opens on a backlog, so there each length also goes up to as
+    many as the first ``slots`` requests hold of it, since the first
+    admission round takes whichever of them are due by then; a warm
+    replica meets each request as it comes."""
+    first = [len(r.prompt) for r in reqs[: mix["slots"]]] if mix["start"] == "cold" else []
     return sorted({(g, S) for S in mix["prompt_lens"]
                    for g in range(1, max(mix["warm_group"], first.count(S)) + 1)})
 
@@ -563,7 +566,7 @@ def run_cell(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float, tr
     if loop.is_alive():
         raise RuntimeError("the serving loop did not stop within 120 s of the window's close")
 
-    rec = Record(arch=arch, mix=mix, seconds=seconds, cold=cold_report,
+    rec = Record(arch=arch, mix=mix, seconds=seconds, cold=cold_report, t_open=t0,
                  compiles=counter.compiles, lateness_s=lateness,
                  device_kind=devices[0].device_kind)
     rec.steps = list(inst.steps)

@@ -1,7 +1,7 @@
-"""Seconds the in-window cold start spent building the tier-1 placeholders
-on the host, putting them on the device and waiting for every put, tier-0's
-too (the program's ColdStartReport.placeholder_s, span
-repro.cold.placeholder)."""
+"""Seconds the in-window cold start spent allocating the tier-1
+placeholders on the device and waiting once for every put, tier-0's too
+(the program's ColdStartReport.placeholder_s, span repro.cold.placeholder,
+which holds repro.cold.put_wait)."""
 
 
 def read(rec):

@@ -1,13 +1,15 @@
 """The program's own spans (``repro.utils.spans``), windowed for the
 per-layer readers in ``bench/metrics``.
 
-The window runs from the in-window cold start's start on the span clock
-(``ColdStartReport.t_start``) for the window's length: in a cold cell the
-window opens with the cold start, and set-up runs no fault and no step. A
-warm cell has no such mark, and reads nothing until it has one of its own.
-A program without the recorder, or a ring that has dropped spans of the
-window, reads nothing either (None). A span that did not occur in the
-window reads 0."""
+The window runs for the window's length from its opening on the span
+clock (``time.perf_counter``): in a cold cell from the in-window cold
+start's start (``ColdStartReport.t_start``), since the window opens with
+it and set-up runs no fault and no step; in a warm cell, which has no cold
+start, from the harness's own mark (``Record.t_open``), so the steps that
+warmed the replica in set-up stay out. A cold report without ``t_start``,
+a program without the recorder, or a ring that has dropped spans of the
+window reads nothing (None). A span that did not occur in the window
+reads 0."""
 
 from __future__ import annotations
 
@@ -20,13 +22,13 @@ FAULT_SPANS = ("repro.engine.row_ensure", "repro.engine.expert_ensure")
 
 def window(rec) -> Optional[list]:
     """The span records that started in the window, or None."""
-    if not rec.cold or "t_start" not in rec.cold:
+    t0 = rec.cold.get("t_start") if rec.cold else rec.t_open
+    if t0 is None:
         return None
     try:
         spans = importlib.import_module("repro.utils.spans")
     except ImportError:  # a program that records no spans
         return None
-    t0 = rec.cold["t_start"]
     return spans.RECORDER.between(t0, t0 + rec.seconds)
 
 

@@ -7,13 +7,14 @@ JSON (the recorded trace the tests read):
   device  {device name: {"ops": [(name, start_ns, dur_ns)],
                          "modules": [(name, start_ns, dur_ns)]}}
   spans   [(name, start_ns, dur_ns)] host annotations named ``bench.*``
+          (the harness's) or ``repro.*`` (the program's own spans)
 
 ``reduce`` turns that into the device's busy time (the union of the
 intervals in which an operation ran, per chip, averaged over the chips),
 the traced window, each program's device time, the programs that ran
 inside each kind of host span (device time and executions), and the
 longest idle gaps. A program or a gap belongs to the innermost host span
-open at its midpoint.
+open at its midpoint, so a gap inside a program span is named by it.
 """
 
 from __future__ import annotations
@@ -25,7 +26,7 @@ import json
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 DEVICE_PREFIX = "/device:TPU:"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "repro.")
 OP_NAME_CHARS = 160
 
 
@@ -95,7 +96,7 @@ def _label(spans, t: int):
 def reduce(ev: dict, window_ns: tuple[int, int] | None = None, top: int = 10) -> dict:
     """Busy and idle time over ``window_ns`` (default: from the first to
     the last event of any device), per-program device time, per kind of
-    ``bench.*`` span the (device seconds, executions) of each program that
+    host span the (device seconds, executions) of each program that
     ran in it, the ``top`` device operations by time and the ``top``
     longest idle gaps by host span."""
     devs = {d: r for d, r in ev["device"].items() if r["ops"] or r["modules"]}

@@ -148,8 +148,9 @@ HAND = {
 
 
 def test_a_program_span_names_the_gap_inside_a_harness_span():
-    """Given the program's spans (``load`` keeps only the harness's), the
-    reduction names a gap by the innermost of them and moves nothing else."""
+    """Given the program's spans (``load`` keeps them beside the
+    harness's), the reduction names a gap by the innermost of them and
+    moves nothing else."""
     r = trace.reduce(HAND, (0, 100))
     # the idle gap [10, 90] has its midpoint inside the program's read
     assert r["idle_gaps"] == [["repro.tier1.read", pytest.approx(80e-9)]]
@@ -178,8 +179,8 @@ def test_recorded_slice_reduces_as_before_with_program_spans():
 def test_program_spans_reach_the_profile_beside_the_harness_spans(tmp_path):
     """A profile taken here (CPU): the program's spans land in it as
     annotations of their own names, beside the harness's; ``load`` keeps
-    the harness's ``bench.*`` spans only, so the benchmark's labels stay
-    the harness's."""
+    both, the harness's ``bench.*`` and the program's ``repro.*``, and
+    nothing else, so a gap is named by the innermost of them."""
     from jax.profiler import ProfileData
 
     f = jax.jit(lambda v: v * 2)
@@ -197,5 +198,5 @@ def test_program_spans_reach_the_profile_beside_the_harness_spans(tmp_path):
            if not plane.name.startswith("/device:") for line in plane.lines for e in line.events}
     assert {"bench.fault", "repro.tier1.read", "elsewhere.span"} <= raw
     names = {n for n, _, _ in trace.load(path)["spans"]}
-    assert "bench.fault" in names
-    assert not names & {"repro.tier1.read", "elsewhere.span"}
+    assert {"bench.fault", "repro.tier1.read"} <= names
+    assert "elsewhere.span" not in names

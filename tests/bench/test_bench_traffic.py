@@ -15,7 +15,8 @@ import bench_tiny  # noqa: E402
 from bench import harness, traffic  # noqa: E402
 
 SEEDS = [0, 7, 2**31 + 12345, 2**40 + 3]
-MIXES = {"cold_burst": traffic.load_mix("cold_burst"), "toy_warm": bench_tiny.MIX}
+MIXES = {"cold_burst": traffic.load_mix("cold_burst"), "toy_warm": bench_tiny.MIX,
+         "steady_chat": traffic.load_mix("steady_chat")}
 
 
 @pytest.mark.parametrize("mix", sorted(MIXES))
