@@ -8,7 +8,8 @@ only what the program lacks: seeded weights (``bench.weights``), an
 open-loop generator thread that submits each request when it is due,
 wrappers around the engine's two step calls that record when each step
 ended and what it computed, host spans for the profiler, and the plain
-reference that decides ``correct`` once the window has closed.
+reference of the configuration's family (``bench/arch.py``) that decides
+``correct`` once the window has closed.
 
 Timing starts at each request's due time, not at its submission, so a
 stall shows in every request that waits behind it.
@@ -36,8 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import traffic, weights, work
-from bench.arch import Arch, arch_of, program_config
-from bench.reference import mistral as reference
+from bench.arch import arch_of, load_reference, program_config
 
 BENCH = Path(__file__).resolve().parent
 WORKDIR = BENCH / ".work"  # artifacts and traces of a run; git-ignored
@@ -64,7 +64,7 @@ class Record:
     """What one run saw, read by the end-to-end metrics and by the
     per-layer readers in ``bench/metrics``."""
 
-    arch: Arch
+    arch: object                                   # the family's shapes (bench/arch.py)
     mix: dict
     seconds: float
     requests: list = field(default_factory=list)   # dicts, see _request_rows
@@ -331,15 +331,16 @@ def _judged(stats: dict, limits: dict) -> dict:
     return {k: {"value": stats.get(k), "limit": v, "need": NEEDS[k]} for k, v in limits.items()}
 
 
-def check(arch: Arch, seed: int, sample: list[dict], pad_to: int, limits: dict,
+def check(reference, arch, seed: int, sample: list[dict], pad_to: int, limits: dict,
           *, control: bool = False, log=print) -> tuple[dict, Optional[dict]]:
-    """The reference re-reads each sampled request (prompt and served
-    tokens) and reads, at every served token, how far that token's logit
-    lies below the reference's best. Of those gaps (``_gap_stats``) the
-    numbers that ``limits`` names are compared. ``control`` also judges,
-    by the same limits, the tokens the fp8 reference puts first on the
-    same prompts and tokens, as if they had been served; the second value
-    returned is that judgement, else None."""
+    """The family's plain reference (a module, ``bench/arch.py``) re-reads
+    each sampled request (prompt and served tokens) and reads, at every
+    served token, how far that token's logit lies below the reference's
+    best. Of those gaps (``_gap_stats``) the numbers that ``limits`` names
+    are compared. ``control`` also judges, by the same limits, the tokens
+    the fp8 reference puts first on the same prompts and tokens, as if
+    they had been served; the second value returned is that judgement,
+    else None."""
     if not sample:
         return _judged({"tokens_compared": 0}, limits), None
     seqs = [np.concatenate([r["prompt"], np.asarray(r["out"][:-1], np.int32)]) for r in sample]
@@ -421,7 +422,7 @@ class _Stalls:
                 + str([(round(a - t0, 3), d, g) for a, d, g in longest]))
 
 
-def _artifact(model, arch: Arch, conf: dict, result, seed: int, workdir: Path, log) -> Path:
+def _artifact(model, arch, conf: dict, result, seed: int, workdir: Path, log) -> Path:
     """The seed's two-tier artifact: built once and kept, one per
     configuration, keyed by the configuration file and the seed, so a
     later run of the same seed reads it rather than building it again."""
@@ -446,10 +447,12 @@ def _artifact(model, arch: Arch, conf: dict, result, seed: int, workdir: Path, l
 
 def run_cell(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float, trace: bool,
              e2e_names: list[str], per_layer: list[str], t_start: float,
-             control: bool = False, workdir: Path = WORKDIR, log=print) -> dict:
+             control: bool = False, workdir: Path = WORKDIR, root: Path = BENCH,
+             log=print) -> dict:
     """One run; returns the result line's object (see ``bench/run.py``).
     ``control`` also judges the fp8 control on the same sample
-    (``control_correct``, ``control_checks``)."""
+    (``control_correct``, ``control_checks``). The configuration's family
+    and the per-layer readers are found under ``root``."""
     from repro.launch import serve
     from repro.models.zoo import build_model
     from repro.serving import ColdStartServer, ColdStartReport, ContinuousBatchingScheduler
@@ -457,7 +460,8 @@ def run_cell(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float, tr
     from repro.serving.scheduler import RequestQueue
 
     devices = jax.local_devices()[: cell["chips"]]
-    arch = arch_of(conf)
+    arch = arch_of(conf, root)
+    reference = load_reference(conf, root)
     model = build_model(program_config(arch))
     reqs = traffic.schedule(mix, seed, seconds, arch.vocab)
     max_seq = traffic.max_seq(mix)
@@ -599,13 +603,13 @@ def run_cell(cell: dict, conf: dict, mix: dict, *, seed: int, seconds: float, tr
     del sched, engine, server, inst, loop
     gc.collect()
     jax.clear_caches()
-    checks, control_checks = check(arch, seed, sample, max_seq, conf["check"],
+    checks, control_checks = check(reference, arch, seed, sample, max_seq, conf["check"],
                                    control=control, log=log)
 
     if trace:
         metrics = {}
         for name, unit in per_layer:
-            v = load_reader(name)(rec)
+            v = load_reader(name, root)(rec)
             if v is not None:
                 metrics[name] = {"value": v, "unit": unit}
     else:

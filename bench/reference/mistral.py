@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
-from bench.arch import Arch
+from bench.families.mistral import Arch
 
 NEG = -1e30
 Q_CHUNK = 512  # queries per attention block, so scores never hold (P, P) for all heads

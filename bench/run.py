@@ -5,7 +5,9 @@
 Run from the root of a checkout. The cell, its configuration, its traffic
 mix and its metrics are named in ``BENCHMARK.json``; each configuration
 is ``bench/configs/<name>.json``, each mix ``bench/traffic/<name>.json``,
-each per-layer metric ``bench/metrics/<name>.py``. With ``--trace 0``
+each per-layer metric ``bench/metrics/<name>.py``, and a configuration's
+family (its ``reference``) has its adapter in ``bench/families/<family>.py``
+and its plain reference in ``bench/reference/<family>.py``. With ``--trace 0``
 the result carries the cell's end-to-end metrics, with ``--trace 1`` its
 per-layer metrics, from a run whose window is partly traced.
 

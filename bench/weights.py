@@ -17,41 +17,10 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-from bench.arch import Arch
+from bench.arch import family_of
 
 SQRT3 = math.sqrt(3.0)
 CHUNK = 1 << 27  # elements drawn at once, at most, for a leaf of three or more axes
-
-
-def leaf_specs(arch: Arch) -> dict[str, tuple[tuple, str, float]]:
-    """name -> (shape, kind, scale). Layer leaves are stacked over the
-    layers (leading axis). ``kind`` is ``"w"`` (uniform, std = scale) or
-    ``"norm"`` (1 + uniform of std ``scale``)."""
-    L, D, V = arch.layers, arch.d_model, arch.vocab
-    q, kv = arch.heads * arch.head_dim, arch.kv_heads * arch.head_dim
-    s = {
-        "embed": ((V, D), "w", 1 / math.sqrt(D)),
-        "head": ((V, D), "w", 1 / math.sqrt(D)),
-        "final_norm": ((D,), "norm", 0.1),
-        "norm1": ((L, D), "norm", 0.1),
-        "norm2": ((L, D), "norm", 0.1),
-        "wq": ((L, D, q), "w", 1 / math.sqrt(D)),
-        "wk": ((L, D, kv), "w", 1 / math.sqrt(D)),
-        "wv": ((L, D, kv), "w", 1 / math.sqrt(D)),
-        "wo": ((L, q, D), "w", 1 / math.sqrt(q)),
-    }
-    F = arch.d_ff
-    if arch.moe:
-        E = arch.experts
-        s["router"] = ((L, D, E), "w", 1 / math.sqrt(D))
-        s["w_gate"] = ((L, E, D, F), "w", 1 / math.sqrt(D))
-        s["w_up"] = ((L, E, D, F), "w", 1 / math.sqrt(D))
-        s["w_down"] = ((L, E, F, D), "w", 1 / math.sqrt(F))
-    else:
-        s["w_gate"] = ((L, D, F), "w", 1 / math.sqrt(D))
-        s["w_up"] = ((L, D, F), "w", 1 / math.sqrt(D))
-        s["w_down"] = ((L, F, D), "w", 1 / math.sqrt(F))
-    return s
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -90,45 +59,26 @@ def _draw(key, name: str, shape: tuple, kind: str, scale: float, dtype) -> jax.A
     return _values(k, shape, kind, scale, dtype)
 
 
-def leaf(arch: Arch, seed: int, name: str) -> jax.Array:
+def leaf(arch, seed: int, name: str) -> jax.Array:
     """One leaf, in the served dtype, made on the default device."""
-    shape, kind, scale = leaf_specs(arch)[name]
+    shape, kind, scale = family_of(arch).leaf_specs(arch)[name]
     fn = jax.jit(lambda key: _draw(key, name, shape, kind, scale, jnp.dtype(arch.dtype)))
     return fn(seed_key(seed))
 
 
-# program tree path -> the benchmark's leaf name
-_PROGRAM_PATHS = {
-    "embed": "embed",
-    "head": "head",
-    "final_norm": "final_norm",
-    "groups.u0.norm1": "norm1",
-    "groups.u0.norm2": "norm2",
-    "groups.u0.attn.wq": "wq",
-    "groups.u0.attn.wk": "wk",
-    "groups.u0.attn.wv": "wv",
-    "groups.u0.attn.wo": "wo",
-    "groups.u0.dense.w_gate": "w_gate",
-    "groups.u0.dense.w_up": "w_up",
-    "groups.u0.dense.w_down": "w_down",
-    "groups.u0.moe.router": "router",
-    "groups.u0.moe.w_gate": "w_gate",
-    "groups.u0.moe.w_up": "w_up",
-    "groups.u0.moe.w_down": "w_down",
-}
-
-
-def program_params(arch: Arch, model, seed: int) -> dict:
+def program_params(arch, model, seed: int) -> dict:
     """The program's parameter tree, every leaf made on the device by one
-    jitted call in the served dtype. Raises if the program's tree has a
-    leaf this benchmark does not know or a shape it does not expect."""
+    jitted call in the served dtype, laid out by the family's
+    ``leaf_specs`` and ``program_paths``. Raises if the program's tree has
+    a leaf this benchmark does not know or a shape it does not expect."""
     from repro.utils.tree import flatten_with_paths, tree_from_flat
 
-    specs = leaf_specs(arch)
+    fam = family_of(arch)
+    specs, paths = fam.leaf_specs(arch), fam.program_paths(arch)
     dtype = jnp.dtype(arch.dtype)
     plan = {}
     for path, a in flatten_with_paths(model.abstract()):
-        name = _PROGRAM_PATHS.get(path)
+        name = paths.get(path)
         if name is None or tuple(a.shape) != specs[name][0] or a.dtype != dtype:
             raise ValueError(f"program leaf {path} {a.shape} {a.dtype} has no "
                              f"benchmark leaf of that shape and dtype")

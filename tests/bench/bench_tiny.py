@@ -21,7 +21,8 @@ MOE_CHECK = {"tokens_compared": 40, "beyond_2ulp_share": 0.035}
 
 
 def conf(experts: int) -> dict:
-    return {"name": f"toy-{experts}e", "hidden_size": 128, "intermediate_size": 256,
+    return {"name": f"toy-{experts}e", "reference": "bench/reference/mistral.py",
+            "hidden_size": 128, "intermediate_size": 256,
             "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
             "num_hidden_layers": 2, "num_local_experts": experts,
             "num_experts_per_tok": 2 if experts else 0, "vocab_size": 1024,

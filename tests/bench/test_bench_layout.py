@@ -11,7 +11,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from bench import harness, traffic  # noqa: E402
-from bench.arch import arch_of, load_config  # noqa: E402
+from bench.arch import arch_of, family_name, load_config, load_family, load_reference  # noqa: E402
 from bench.run import cell_spec  # noqa: E402
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -52,7 +52,14 @@ def test_new_files_are_found_by_name(tmp_path):
 def test_every_name_in_benchmark_json_has_its_file():
     for c in BENCH["configs"]:
         assert (ROOT / c["file"]).is_file()
-        assert load_config(c["name"])["name"] == c["name"]
+        conf = load_config(c["name"])
+        assert conf["name"] == c["name"]
+        # the family it names: its plain reference and its adapter
+        family = family_name(conf)
+        assert (ROOT / conf["reference"]).is_file()
+        assert (ROOT / "bench" / "families" / f"{family}.py").is_file()
+        assert callable(load_reference(conf).logits)
+        assert load_family(conf).arch_of(conf).name == c["name"]
     for w in BENCH["workloads"]:
         traffic.load_mix(w["traffic"])
     for m in BENCH["per_layer"]:

@@ -13,7 +13,8 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from bench import weights  # noqa: E402
-from bench.arch import Arch, program_config  # noqa: E402
+from bench.arch import program_config  # noqa: E402
+from bench.families.mistral import Arch  # noqa: E402
 from bench.reference import mistral as reference  # noqa: E402
 
 BASE = dict(name="toy", layers=2, d_model=64, heads=4, kv_heads=2, head_dim=16, d_ff=96,

@@ -6,7 +6,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from bench import work  # noqa: E402
-from bench.arch import Arch  # noqa: E402
+from bench.families.mistral import Arch  # noqa: E402
 
 # 2 layers, hidden 8, 2 query heads and 1 key/value head of 4, MLP 16, vocab 32
 DENSE = Arch(name="hand", layers=2, d_model=8, heads=2, kv_heads=1, head_dim=4, d_ff=16,
